@@ -391,14 +391,17 @@ def _sharded_tail_grads(stack, front, labels, p_o: Params,
                         p_s: List[Params], p_l: Params, m_l: int, N: int,
                         B: int, mesh):
     """Loss + grads with the cloud tail ``m_l..N`` data-parallel under
-    ``shard_map``.  Two-stage composition: ``jax.vjp`` through the front,
-    then the tail's ``value_and_grad`` *inside* the mapped body — param
-    grads and the per-sample-sum loss are ``psum``-reduced over the dp
-    axes while the activation cotangent stays batch-sharded and flows
-    back through the front's VJP."""
+    ``shard_map``.  One mapped body per dp shard: the front runs
+    replicated on every shard (its Pallas kernels cannot be
+    auto-partitioned, so it must sit inside the manual region too), the
+    shard takes its slice of the arrived batch through the tail's
+    ``value_and_grad``, and ``jax.vjp`` carries that slice's activation
+    cotangent back through the front.  Loss and every parameter grad
+    are ``psum``-reduced over the dp axes, which adds the slices'
+    contributions exactly once."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distrib import compat, sharding
+    from repro.distrib import sharding
 
     dp = sharding.dp_axes(mesh)
     if not dp:
@@ -413,28 +416,32 @@ def _sharded_tail_grads(stack, front, labels, p_o: Params,
             f"global batch {B} is not divisible by the cloud mesh's "
             f"{n_shards} data-parallel shards; pick a schedule whose "
             "batch split is a multiple of the dp size")
-
-    cur, front_vjp = jax.vjp(front, p_o, p_s, p_l)
+    n_local = B // n_shards
 
     def tail_loss(p_o: Params, cur: jax.Array, lab: jax.Array) -> jax.Array:
         return stack.sum_loss(stack.apply_segment(p_o, cur, m_l, N), lab)
 
-    def body(p_o: Params, cur_l: jax.Array, lab_l: jax.Array):
-        loss_l, (gp_l, gc_l) = jax.value_and_grad(
+    def body(p_o: Params, p_s: List[Params], p_l: Params,
+             lab_l: jax.Array):
+        cur, front_vjp = jax.vjp(front, p_o, p_s, p_l)
+        start = jax.lax.axis_index(dp) * n_local
+        cur_l = jax.lax.dynamic_slice_in_dim(cur, start, n_local)
+        loss_l, (gp_tail, gc_l) = jax.value_and_grad(
             tail_loss, argnums=(0, 1))(p_o, cur_l, lab_l)
-        gp = jax.tree.map(lambda t: jax.lax.psum(t, dp), gp_l)
-        return jax.lax.psum(loss_l, dp), gp, gc_l
+        g_cur = jax.lax.dynamic_update_slice_in_dim(
+            jnp.zeros_like(cur), gc_l, start, 0)
+        g_o, g_s, g_l = front_vjp(g_cur)
+        g_o = jax.tree.map(jnp.add, g_o, gp_tail)
+        return jax.lax.psum((loss_l, g_o, g_s, g_l), dp)
 
-    spec_cur = P(dp, *([None] * (cur.ndim - 1)))
     spec_lab = P(dp, *([None] * (labels.ndim - 1)))
-    sharded = compat.shard_map(
-        body, in_specs=(P(), spec_cur, spec_lab),
-        out_specs=(P(), P(), spec_cur), axis_names=set(dp),
-        check_vma=False, mesh=mesh)
-    total_loss, g_o_tail, g_cur = sharded(p_o, cur, labels)
-    g_o_front, g_s, g_l = front_vjp(g_cur)
-    g_o = jax.tree.map(jnp.add, g_o_front, g_o_tail)
-    return total_loss, g_o, g_s, g_l
+    sharded = jax.shard_map(
+        body, in_specs=(P(), P(), P(), spec_lab), out_specs=P(),
+        axis_names=set(dp), check_vma=False, mesh=mesh)
+    # jit: jax's eager shard_map path rejects a partially manual mesh
+    # (it re-checks the specs against every mesh axis); traced, it is fine.
+    # repro-lint: disable-next=RA102 inlined into the caller's jitted step; only eager calls rebuild it
+    return jax.jit(sharded)(p_o, p_s, p_l, labels)
 
 
 def tree_stream_edges(profile, net, sched: MultiSchedule) -> Tuple[int, ...]:

@@ -3,7 +3,8 @@
 * :func:`flash_attention` — model-layout GQA flash attention with a
   memory-O(T * block) chunked backward (consumes the kernel's LSE).
 * :func:`gla_scan` — chunked gated linear recurrence; backward via the
-  linear-memory jnp reference.
+  chunked jnp reference (``models/lm/gla.chunked_gla``), whose saved
+  state is per chunk, not per step.
 * :func:`quantize_int8` / :func:`dequantize_int8` — unbiased int8
   compression for the tiered gradient sync.
 
@@ -23,6 +24,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import gla_scan as gs
 from repro.kernels import int8_quant as iq
 from repro.kernels import ref
+from repro.models.lm.gla import chunked_gla
 
 
 def _interpret() -> bool:
@@ -34,11 +36,22 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 def _pick_block(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target (prefers multiples of 128)."""
-    b = min(target, n)
-    while n % b:
-        b -= 1
-    return b
+    """Block length along a sequence axis of length ``n``: all of ``n``
+    when it fits ``target``, else the largest multiple of 128 that
+    divides ``n`` and fits.  The TPU tiles the last two block dims in
+    (8, 128) units, so any other divisor would be refused at compile
+    time; raise here instead."""
+    if n <= target:
+        return n
+    b = target - target % 128
+    while b >= 128:
+        if n % b == 0:
+            return b
+        b -= 128
+    raise ValueError(
+        f"no TPU-legal block for a sequence of {n}: it is longer than the "
+        f"{target}-row block and no multiple of 128 up to {target} "
+        f"divides it")
 
 
 @functools.lru_cache(maxsize=None)
@@ -143,9 +156,15 @@ def _make_gla(chunk: int, normalize: bool, interpret: bool):
 
     def bwd(res, cts):
         q, k, v, a = res
-        _, vjp = jax.vjp(
-            lambda q, k, v, a: ref.ref_gla(q, k, v, a, normalize=normalize),
-            q, k, v, a)
+
+        def chunked(q, k, v, a):
+            # Kernel layout [BH, T, d] as one-head model layout.
+            y, (S, n) = chunked_gla(q[:, :, None], k[:, :, None],
+                                    v[:, :, None], a[:, :, None],
+                                    chunk=chunk, normalize=normalize)
+            return y[:, :, 0], S[:, 0], n[:, 0]
+
+        _, vjp = jax.vjp(chunked, q, k, v, a)
         return vjp(cts)
 
     f.defvjp(fwd, bwd)
@@ -160,7 +179,6 @@ def gla_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     log_decay [B, T, H].  Contract matches chunked_gla."""
     if initial_state is not None:
         # decode/chained-prefill path: stay on the jnp reference.
-        from repro.models.lm.gla import chunked_gla
         return chunked_gla(q, k, v, log_decay, chunk=chunk,
                            normalize=normalize, initial_state=initial_state,
                            use_kernel=False)
@@ -184,9 +202,9 @@ def gla_scan(q: jax.Array, k: jax.Array, v: jax.Array,
 def quantize_int8(x: jax.Array, key: jax.Array, *,
                   interpret: Optional[bool] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Flattens to 2D rows of <= 2**14 lanes, quantizes with stochastic
-    rounding.  Returns (q int8, scale f32 per row) in the 2D layout plus
-    enough info to invert (see :func:`dequantize_int8`)."""
+    """x: [M, N] rows, quantized with stochastic rounding.  Returns
+    (q int8 [M, N], scale f32 [M]), which :func:`dequantize_int8`
+    inverts."""
     interp = _interpret() if interpret is None else interpret
     noise = jax.random.uniform(key, x.shape, jnp.float32)
     return iq.quantize_int8(x, noise, interpret=interp)

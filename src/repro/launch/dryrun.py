@@ -27,7 +27,6 @@ from repro.launch.mesh import V5E, make_production_mesh, mesh_chips  # noqa: E40
 from repro.models.lm.model import build_model  # noqa: E402
 from repro.optim import get_optimizer  # noqa: E402
 from repro.serve.engine import make_decode_step, make_prefill_step  # noqa: E402
-from repro.distrib import compat
 from repro.train.step import make_train_step  # noqa: E402
 
 """Multi-pod dry run: lower + compile every (arch x shape x mesh) cell.
@@ -144,7 +143,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, *,
         jitted = jax.jit(step, in_shardings=(sshard, bshard, repl),
                          out_shardings=(sshard, None),
                          donate_argnums=(0,))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(state_shapes, batch_struct, key_struct)
         return lowered, meta
 
@@ -153,7 +152,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, *,
         batch_struct = input_specs(cfg, shape)
         bshard = batch_shardings(mesh, batch_struct)
         jitted = jax.jit(step, in_shardings=(pshard, bshard))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(param_shapes, batch_struct)
         return lowered, meta
 
@@ -168,7 +167,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, *,
     step = make_decode_step(model)
     jitted = jax.jit(step, in_shardings=(pshard, tshard, cshard, repl),
                      out_shardings=(None, cshard), donate_argnums=(2,))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(param_shapes, tok, cache_shapes, pos)
     return lowered, meta
 

@@ -27,7 +27,9 @@ V5E = Hardware()
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # shard_hint constrains only Auto axes; make_mesh defaults to Explicit.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_chips(mesh) -> int:

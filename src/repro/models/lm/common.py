@@ -19,25 +19,9 @@ Params = Dict[str, Any]
 
 
 def ambient_abstract_mesh():
-    """The mesh currently in scope, or ``None``.
-
-    ``jax.sharding.get_abstract_mesh`` only exists in newer jax releases;
-    older ones keep it in ``jax._src.mesh`` (where the empty sentinel is not
-    always an ``AbstractMesh``).  Normalize every "no usable mesh" shape to
-    ``None`` so callers need a single check.
-    """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        try:
-            from jax._src import mesh as _mesh_lib
-            mesh = _mesh_lib.get_abstract_mesh()
-        except (ImportError, AttributeError):
-            return None
-    if mesh is None or getattr(mesh, "empty", False) or \
-            not getattr(mesh, "axis_names", None):
-        return None
-    return mesh
+    """The mesh currently in scope (``jax.set_mesh``), or ``None``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def shard_hint(x: jax.Array, *axes) -> jax.Array:
@@ -52,7 +36,7 @@ def shard_hint(x: jax.Array, *axes) -> jax.Array:
 
     # axes in Manual mode (inside shard_map) cannot appear in constraints
     auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
-            if str(t) == "Auto"}
+            if t == jax.sharding.AxisType.Auto}
 
     def reduce(a, dim):
         """Keep the subset of axis names present in the mesh (and not

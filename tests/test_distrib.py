@@ -2,6 +2,7 @@
 meshes, plus multi-device semantics tests (tiered sync equivalence,
 dry-run micro-cell) run in a subprocess so this pytest process keeps its
 single CPU device."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -15,8 +16,9 @@ from repro.distrib.sharding import batch_spec, cache_spec, param_spec
 
 # An AbstractMesh carries axis names/sizes without real devices — the
 # sharding rules only read those.
-MESH = jax.sharding.AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-SINGLE = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+MESH = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @settings(max_examples=80, deadline=None)
@@ -47,6 +49,22 @@ def test_batch_spec_divisibility(batch, ndim):
             names = spec[0] if isinstance(spec[0], tuple) else (spec[0],)
             prod = int(np.prod([mesh.shape[a] for a in names]))
             assert batch % prod == 0
+
+
+@pytest.mark.parametrize("axis_type,constrained", [
+    (jax.sharding.AxisType.Auto, True),
+    (jax.sharding.AxisType.Explicit, False)])
+def test_shard_hint_constrains_auto_axes(axis_type, constrained):
+    """``shard_hint`` names only ``Auto`` mesh axes: under one it emits a
+    constraint on that axis, under an ``Explicit`` one it names none."""
+    import jax.numpy as jnp
+
+    from repro.models.lm.common import shard_hint
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(axis_type,))
+    with jax.set_mesh(mesh):
+        txt = jax.jit(lambda x: shard_hint(x * 2, "data", None)).lower(
+            jnp.ones((4, 4))).as_text()
+    assert ('[{"data"}, {}]' in txt) == constrained, txt
 
 
 def test_cache_spec_kv_vs_seq():
@@ -132,11 +150,11 @@ def _run_subprocess(code: str):
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=540,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", REPO),
              # the image ships libtpu: without an explicit platform pin
              # jax probes for TPU hardware for minutes before falling
              # back to CPU (the parent test env pins it too).
-             "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+             "JAX_PLATFORMS": "cpu"}, cwd=REPO)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
     return out.stdout
 
@@ -149,10 +167,10 @@ def test_tiered_sync_equivalence_multidev():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.distrib import compat
         from repro.distrib.tiered_sync import (choose_tiers,
                                                tiered_grad_sync)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         grads = {"big": jax.random.normal(jax.random.PRNGKey(0),
                                           (8, 64, 32)),
                  "small": jax.random.normal(jax.random.PRNGKey(1), (8, 8))}
@@ -164,12 +182,12 @@ def test_tiered_sync_equivalence_multidev():
             # check_vma=False as in the production step: the compressed
             # path's output is replicated by construction (identical
             # all-gather + arithmetic on every pod) but not provably so.
-            return compat.shard_map(per_pod, in_specs=(P("pod"), P()),
+            return jax.shard_map(per_pod, in_specs=(P("pod"), P()),
                                  out_specs=P(), axis_names={"pod"},
                                  check_vma=False)(g, key)
 
         key = jax.random.PRNGKey(42)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             plain = jax.jit(lambda g, k: sync(g, k, None))(grads, key)
             want = jax.tree.map(
                 lambda g: g.reshape(2, 4, *g.shape[1:]).mean(0), grads)
@@ -208,7 +226,8 @@ def test_tree_sharded_cloud_tier_multidev():
         specs = tuple(DenseSpec(f"fc{i}", 16) for i in range(4)) + \\
             (DenseSpec("out", 5, relu=False),)
         model = LayeredModel("tiny_mlp", specs, (8,), 5)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         sched = MultiSchedule(
             worker_o="cloud", worker_l="device_3",
             s_workers=("device_0", "device_1", "device_2", "edge_0",
@@ -254,7 +273,6 @@ def test_dryrun_micro_cell():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_arch
-        from repro.distrib import compat
         from repro.distrib import (batch_shardings, choose_tiers,
                                    opt_state_shardings, param_shardings)
         from repro.models.lm.model import build_model
@@ -262,7 +280,8 @@ def test_dryrun_micro_cell():
         from repro.train.step import make_train_step
         from repro.launch.hlo_analysis import loop_aware_cost
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         cfg = get_arch("qwen2.5-3b").smoke
         model = build_model(cfg)
         opt = get_optimizer("adamw")
@@ -278,7 +297,7 @@ def test_dryrun_micro_cell():
                              compute_seconds=1e-9)
         step = make_train_step(model, opt, microbatches=2, hier_sync=True,
                                tiers=tiers)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(sshard, bshard,
                                                  NamedSharding(mesh, P())),
                              out_shardings=(sshard, None))

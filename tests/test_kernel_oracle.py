@@ -183,12 +183,16 @@ def test_quantize_int8_oracle(m, n, kind, stochastic, seed):
                                rtol=2.4e-7, atol=0.0)
 
 
-def test_quantize_int8_block_tiling_invariance():
-    """Row-blocked grids must not change results (per-row scaling)."""
-    x = jax.random.normal(jax.random.PRNGKey(3), (12, 64), jnp.float32)
-    noise = jnp.full((12, 64), 0.5, jnp.float32)
-    base = iq.quantize_int8(x, noise, block_rows=12, interpret=True)
-    for br in (1, 2, 3, 4, 6):
+def test_quantize_int8_block_tiling_invariance(monkeypatch):
+    """Row blocks, lane tiles and padding must not change results: the
+    scale is the absmax of the whole row, however it is tiled."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (20, 640), jnp.float32)
+    noise = jnp.full((20, 640), 0.5, jnp.float32)
+    base = iq.quantize_int8(x, noise, block_rows=20, interpret=True)
+    # (row block target, tile elements): 8-row blocks over rows padded
+    # to 24, then 128-lane tiles (5 per row) with and without row blocks.
+    for br, tile in ((8, iq.TILE_ELEMS), (8, 8 * 128), (20, 20 * 256)):
+        monkeypatch.setattr(iq, "TILE_ELEMS", tile)
         q, s = iq.quantize_int8(x, noise, block_rows=br, interpret=True)
         np.testing.assert_array_equal(np.asarray(q), np.asarray(base[0]))
         np.testing.assert_array_equal(np.asarray(s), np.asarray(base[1]))

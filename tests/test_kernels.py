@@ -67,12 +67,24 @@ def test_flash_lse_matches_ref():
     np.testing.assert_allclose(lse, lse_ref, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("n,want", [(300, 300), (512, 512), (1152, 384),
+                                    (4096, 512), (1000, None)])
+def test_pick_block_is_tpu_legal(n, want):
+    """A sequence block is the whole sequence or a multiple of 128 that
+    divides it; anything else is refused here, not by the TPU compiler."""
+    if want is None:
+        with pytest.raises(ValueError, match="no TPU-legal block"):
+            ops._pick_block(n, 512)
+    else:
+        assert ops._pick_block(n, 512) == want
+
+
 def test_flash_grads_match_ref():
-    q, k, v = _qkv(1, 128, 4, 2, 64)
+    q, k, v = _qkv(1, 256, 4, 2, 64)
 
     def f_kernel(q, k, v):
         return (ops.flash_attention(q, k, v, causal=True, window=32,
-                                    block_q=64, block_k=64,
+                                    block_q=128, block_k=128,
                                     interpret=True) ** 2).sum()
 
     def f_ref(q, k, v):

@@ -2,6 +2,7 @@
 bit-identical to the per-fleet engines, the plan-cache fingerprint must
 be deterministic across processes and separate near-misses, and the
 cache itself must obey its LRU/telemetry contract."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -16,6 +17,8 @@ from repro.core.scheduler import MultiSchedulerResult, SolveManyStats, \
 from repro.serve.planner import (PLAN_CACHE_SIZE, PlanRequest, Planner,
                                  Q_REL, fingerprint, quantize)
 from repro.serve.population import synthetic_population
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _random_stack(seed, K, n_rows, n):
@@ -187,7 +190,8 @@ def test_fingerprint_deterministic_across_processes():
         """)],
         capture_output=True, text=True, timeout=540,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+             "HOME": os.environ.get("HOME", REPO), "JAX_PLATFORMS": "cpu"},
+        cwd=REPO)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
     assert out.stdout.strip() == here
 
