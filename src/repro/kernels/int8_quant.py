@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import pick_block
+
 LANES = 128
 ROW_ALIGN = 8
 TILE_ELEMS = 1 << 18            # 1 MiB of f32 per input tile
@@ -62,32 +64,20 @@ def _quant_kernel(x_ref, u_ref, q_ref, scale_ref, amax_scr):
         scale_ref[...] = jnp.broadcast_to(scale, scale_ref.shape)
 
 
-def _block(n: int, target: int, align: int) -> int:
-    """All of ``n`` when it fits ``target``; else the largest multiple of
-    ``align`` that divides ``n`` and fits (``n`` is a multiple of
-    ``align`` then: rows are padded to 8, lanes to 128, beforehand)."""
-    if n <= target:
-        return n
-    b = max(align, target - target % align)
-    while n % b:
-        b -= align
-    return b
-
-
 def quantize_int8(x: jax.Array, noise: jax.Array, *, block_rows: int = 256,
                   interpret: bool = False):
     """x, noise: [M, N] (noise uniform in [0,1)).  Returns
     (q int8 [M, N], scale f32 [M])."""
     M, N = x.shape
     Mp = M if M <= block_rows else -(-M // ROW_ALIGN) * ROW_ALIGN
-    bm = _block(Mp, block_rows, ROW_ALIGN)
+    bm = pick_block(Mp, block_rows, ROW_ALIGN)
     Np = -(-N // LANES) * LANES
     if (Mp, Np) != (M, N):
         # Zero padding is exact: it never raises a row's absmax, and the
         # padded rows/lanes are cut off below.
         pad = ((0, Mp - M), (0, Np - N))
         x, noise = jnp.pad(x, pad), jnp.pad(noise, pad)
-    bn = _block(Np, TILE_ELEMS // max(bm, ROW_ALIGN), LANES)
+    bn = pick_block(Np, TILE_ELEMS // max(bm, ROW_ALIGN), LANES)
 
     q, scale = pl.pallas_call(
         _quant_kernel,

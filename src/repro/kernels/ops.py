@@ -24,6 +24,7 @@ from repro.kernels import flash_attention as fa
 from repro.kernels import gla_scan as gs
 from repro.kernels import int8_quant as iq
 from repro.kernels import ref
+from repro.kernels.tiling import pick_block
 from repro.models.lm.gla import chunked_gla
 
 
@@ -34,25 +35,6 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 # Flash attention
 # ---------------------------------------------------------------------------
-
-def _pick_block(n: int, target: int) -> int:
-    """Block length along a sequence axis of length ``n``: all of ``n``
-    when it fits ``target``, else the largest multiple of 128 that
-    divides ``n`` and fits.  The TPU tiles the last two block dims in
-    (8, 128) units, so any other divisor would be refused at compile
-    time; raise here instead."""
-    if n <= target:
-        return n
-    b = target - target % 128
-    while b >= 128:
-        if n % b == 0:
-            return b
-        b -= 128
-    raise ValueError(
-        f"no TPU-legal block for a sequence of {n}: it is longer than the "
-        f"{target}-row block and no multiple of 128 up to {target} "
-        f"divides it")
-
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
@@ -76,7 +58,7 @@ def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
         BH, T, hd = q.shape
         BKV, S, _ = k.shape
         rep = BH // BKV
-        bk = _pick_block(S, block_k)
+        bk = pick_block(S, block_k)
         scale = 1.0 / (hd ** 0.5)
 
         qf = q.astype(jnp.float32).reshape(BKV, rep, T, hd)
@@ -127,8 +109,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
     interp = _interpret() if interpret is None else interpret
-    bq = _pick_block(T, block_q)
-    bk = _pick_block(S, block_k)
+    bq = pick_block(T, block_q)
+    bk = pick_block(S, block_k)
     f = _make_flash(causal, int(window), bq, bk, interp)
     qh = q.swapaxes(1, 2).reshape(B * H, T, hd)
     kh = k.swapaxes(1, 2).reshape(B * KV, S, hd)

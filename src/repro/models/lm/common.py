@@ -203,8 +203,11 @@ def chunked_softmax_xent(hidden: jax.Array, lm_head: jax.Array,
         # keep the [B, chunk, V] chunk sharded: batch over DP, vocab TP.
         logits = shard_hint(logits, ("pod", "data"), None, "model")
         logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yc[..., None],
-                                   axis=-1)[..., 0]
+        # One-hot select, not take_along_axis: exact (the other terms add
+        # +0.0), and a gather along the vocab-sharded dim inside the
+        # hier_sync shard_map aborts XLA's SPMD partitioner on jax 0.9.0.
+        gold = (logits * jax.nn.one_hot(yc, logits.shape[-1],
+                                        dtype=logits.dtype)).sum(-1)
         nll = (logz - gold) * mc
         return (carry[0] + nll.sum(), carry[1] + mc.sum()), None
 

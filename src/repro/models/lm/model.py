@@ -269,7 +269,12 @@ def _init_head(key, cfg: LMConfig) -> Params:
 
 
 def _embed_tokens(params: Params, tokens: jax.Array) -> jax.Array:
-    return jnp.take(params["embed"], tokens, axis=0)
+    # Gather from a table split over vocab only.  A table split over both
+    # dims, gathered inside a shard_map that leaves those axes Auto (the
+    # hier_sync step), makes XLA's SPMD partitioner abort on jax 0.9.0
+    # (spmd_partitioner_util.cc "partition_group_list" CHECK).
+    return jnp.take(shard_hint(params["embed"], "model", None), tokens,
+                    axis=0)
 
 
 def _prefix_embeds(params: Params, batch: Dict[str, jax.Array],

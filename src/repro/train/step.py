@@ -112,15 +112,12 @@ def make_train_step(model, optimizer: Optimizer, *,
         # check_vma=False: the model body is full of scans whose carries
         # start as unvarying constants (loss chunks, GLA states, grad
         # accumulators) — strict varying-manual-axis typing would need a
-        # pcast at every one of them.  Every mesh axis is manual: with
-        # only ``pod`` manual, XLA's SPMD partitioner CHECK-fails on this
-        # body's gathers.  The specs never name the other axes, so the
-        # body computes replicated over them; semantics are unchanged.
+        # pcast at every one of them.
         loss, grads = jax.shard_map(
             per_pod,
             in_specs=(P(), P("pod"), P()),
             out_specs=(P(), P()),
-            axis_names=set(jax.sharding.get_abstract_mesh().axis_names),
+            axis_names={"pod"},
             check_vma=False,
         )(state["params"], batch, key)
         params, opt, gnorm = optimizer.update(state["params"], grads,

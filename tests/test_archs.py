@@ -117,3 +117,27 @@ def test_param_counts_near_published():
         shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
         assert abs(n - want) / want < tol, (arch_id, n, want)
+
+
+def test_chunked_xent_matches_gather_reference():
+    """The chunked loss picks the gold logit by a one-hot select; it must
+    match the plain log-softmax gather to f32 rounding, gradient
+    included."""
+    from repro.models.lm.common import chunked_softmax_xent
+    kh, kw, ky = jax.random.split(jax.random.PRNGKey(0), 3)
+    h = jax.random.normal(kh, (2, 64, 16), jnp.float32)
+    w = jax.random.normal(kw, (16, 50), jnp.float32)
+    y = jax.random.randint(ky, (2, 64), 0, 50)
+
+    def ref(h, w):
+        logp = jax.nn.log_softmax(h @ w, axis=-1)
+        return -jnp.take_along_axis(logp, y[..., None], axis=-1).mean()
+
+    got, g_got = jax.value_and_grad(
+        lambda h, w: chunked_softmax_xent(h, w, y, chunk=16),
+        argnums=(0, 1))(h, w)
+    want, g_want = jax.value_and_grad(ref, argnums=(0, 1))(h, w)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)

@@ -264,6 +264,54 @@ def test_tree_sharded_cloud_tier_multidev():
     """)
 
 
+def test_hier_step_keeps_intra_pod_parallelism():
+    """The hier_sync step makes only ``pod`` manual: inside a pod the
+    model stays split over ``data``/``model``, so each chip does the same
+    FLOPs as under the plain data-parallel step.  Making every axis
+    manual would replicate a pod's whole batch on each of its chips (4x
+    here)."""
+    _run_subprocess("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.configs import get_arch
+        from repro.distrib import (batch_shardings, opt_state_shardings,
+                                   param_shardings)
+        from repro.launch.hlo_analysis import loop_aware_cost
+        from repro.models.lm.model import build_model
+        from repro.optim import get_optimizer
+        from repro.train.step import make_train_step
+
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
+        model = build_model(get_arch("qwen2.5-3b").smoke)
+        opt = get_optimizer("adamw")
+        pshapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        oshapes = jax.eval_shape(opt.init, pshapes)
+        state = {"params": pshapes, "opt": oshapes}
+        sshard = {"params": param_shardings(mesh, pshapes),
+                  "opt": opt_state_shardings(mesh, oshapes)}
+        batch = {"tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32),
+                 "targets": jax.ShapeDtypeStruct((8, 64), jnp.int32)}
+        bshard = batch_shardings(mesh, batch)
+        flops = {}
+        for hier in (False, True):
+            step = make_train_step(model, opt, hier_sync=hier)
+            with jax.set_mesh(mesh):
+                compiled = jax.jit(
+                    step, in_shardings=(sshard, bshard,
+                                        NamedSharding(mesh, P())),
+                    out_shardings=(sshard, None)).lower(
+                        state, batch,
+                        jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+            flops[hier] = loop_aware_cost(compiled.as_text())[0]
+        assert flops[False] > 0
+        assert flops[True] <= 1.25 * flops[False], flops
+        print("OK")
+    """)
+
+
 def test_dryrun_micro_cell():
     """A miniature dry-run (8 devices, smoke-scale arch) exercises the
     full lower->compile->analyse path including the hier tiered step."""

@@ -67,16 +67,20 @@ def test_flash_lse_matches_ref():
     np.testing.assert_allclose(lse, lse_ref, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("n,want", [(300, 300), (512, 512), (1152, 384),
-                                    (4096, 512), (1000, None)])
-def test_pick_block_is_tpu_legal(n, want):
-    """A sequence block is the whole sequence or a multiple of 128 that
-    divides it; anything else is refused here, not by the TPU compiler."""
+@pytest.mark.parametrize("n,align,want", [
+    (300, 128, 300), (512, 128, 512), (1152, 128, 384), (4096, 128, 512),
+    (1000, 128, None), (1200, 8, 400), (600, 8, 200), (1000, 8, 200),
+    (1001, 8, None)])
+def test_pick_block_is_tpu_legal(n, align, want):
+    """A block is the whole dim or a multiple of the (8, 128) tile unit
+    that divides it; anything else is refused here, not by the TPU
+    compiler.  Target 512 for every case."""
+    from repro.kernels.tiling import pick_block
     if want is None:
         with pytest.raises(ValueError, match="no TPU-legal block"):
-            ops._pick_block(n, 512)
+            pick_block(n, 512, align)
     else:
-        assert ops._pick_block(n, 512) == want
+        assert pick_block(n, 512, align) == want
 
 
 def test_flash_grads_match_ref():

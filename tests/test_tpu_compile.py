@@ -35,11 +35,9 @@ def one_chip():
     from jax.sharding import SingleDeviceSharding
     prev = os.environ.get("TPU_LOG_DIR")
     os.environ["TPU_LOG_DIR"] = "disabled"      # no compiler logs on disk
-    try:
+    try:    # a topology that cannot be described fails every test here
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
-    except Exception as e:      # the TPU library is absent or held
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     finally:
         if prev is None:
             os.environ.pop("TPU_LOG_DIR", None)
