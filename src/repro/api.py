@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core import pipeline as _pipeline
 from repro.core import scheduler as _scheduler
 from repro.core import simulator as _simulator
@@ -171,8 +172,18 @@ class Plan:
 
         ``cloud_mesh`` (tree fleets only) runs the cloud tail segment
         data-parallel over the mesh's dp axes via ``shard_map``
-        (DESIGN.md §12); the batch must divide by the dp shard count."""
+        (DESIGN.md §12); the batch must divide by the dp shard count.
+
+        Each call is the host span ``hiertrain.step`` (with its step
+        number) around ``hiertrain.split_batch`` and
+        ``hiertrain.dispatch``; a call with a new batch shape notes the
+        program it dispatches (``repro.obs.note_step``), whose compiled
+        text ``repro.obs.step_text`` reads."""
+        import itertools
+
         import jax.numpy as jnp
+
+        from repro.core import hybrid_step as hs
 
         stack = self._require_model()
         sched = self.schedule
@@ -180,34 +191,32 @@ class Plan:
             raise ValueError("cloud_mesh is a tree-topology option; this "
                              f"plan's fleet is {self.fleet.topology!r}")
         if self.fleet.topology == TRIPLE:
-            from repro.core.hybrid_step import (jitted_hybrid_step,
-                                                split_batch)
-            fn = jitted_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                    wire=self.wire)
-
-            def step(params, x, y):
-                return fn(params, split_batch(jnp.asarray(x),
-                                              jnp.asarray(y), sched))
+            fn = hs.jitted_hybrid_step(stack, sched.m_s, sched.m_l, lr,
+                                       wire=self.wire)
+            split = hs.split_batch
         elif self.fleet.topology == TREE:
-            from repro.core.hybrid_step import (jitted_tree_hybrid_step,
-                                                multi_split_batch)
-            fn = jitted_tree_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                         wire=self.wire,
-                                         stream_edge=self.stream_edges(),
-                                         cloud_mesh=cloud_mesh)
-
-            def step(params, x, y):
-                return fn(params, multi_split_batch(jnp.asarray(x),
-                                                    jnp.asarray(y), sched))
+            fn = hs.jitted_tree_hybrid_step(stack, sched.m_s, sched.m_l, lr,
+                                            wire=self.wire,
+                                            stream_edge=self.stream_edges(),
+                                            cloud_mesh=cloud_mesh)
+            split = hs.multi_split_batch
         else:
-            from repro.core.hybrid_step import (jitted_multi_hybrid_step,
-                                                multi_split_batch)
-            fn = jitted_multi_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                          wire=self.wire)
+            fn = hs.jitted_multi_hybrid_step(stack, sched.m_s, sched.m_l, lr,
+                                             wire=self.wire)
+            split = hs.multi_split_batch
+        numbers = itertools.count()
+        noted = []
 
-            def step(params, x, y):
-                return fn(params, multi_split_batch(jnp.asarray(x),
-                                                    jnp.asarray(y), sched))
+        def step(params, x, y):
+            with obs.step_span(next(numbers)):
+                with obs.span("split_batch"):
+                    b = split(jnp.asarray(x), jnp.asarray(y), sched)
+                if noted != [np.shape(x)]:
+                    obs.note_step(fn, (params, b), np.shape(x))
+                    noted[:] = [np.shape(x)]
+                with obs.span("dispatch"):
+                    return fn(params, b)
+
         return step
 
     def init_params(self, key) -> Any:

@@ -34,6 +34,15 @@ The backward pass retraces this routing (handled by AD through the composed
 function — gradients w.r.t. ``params_s`` are exactly what worker_s computes
 after receiving the intermediate result at layer ``m_s+1``).  Weight update:
 per-layer gradient exchange over the *shared* frontend only.
+
+Every phase runs under a named scope (``repro.obs.scope``), so each
+operation of the compiled step names the phase it belongs to:
+``hier.stream{i}`` (TASK-S stream ``i``'s front segment),
+``hier.stream_l`` (TASK L's), ``hier.cloud`` (worker_o's walk),
+``hier.merge`` / ``hier.edge_merge`` (the concatenations at cuts),
+``hier.exchange`` (the sums over each front layer's copies),
+``hier.update`` (SGD) and ``hier.tail_psum`` (the sharded tail's psum);
+the plain step runs under ``reference``.
 """
 from __future__ import annotations
 
@@ -44,6 +53,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.cost_model import MultiSchedule, Schedule
 from repro.core.layerstack import as_layerstack
 from repro.core.wire import wire_act_bytes, wire_codec, wire_grad_bytes
@@ -61,8 +71,9 @@ def reference_sgd_step(model, params: Params, x: jax.Array,
     def loss_fn(p):
         return stack.sum_loss(stack.apply_segment(p, x, 0, N), y) / \
             x.shape[0]
-    loss, grads = jax.value_and_grad(loss_fn)(params)
-    new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+    with obs.scope("reference"):
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
     return new, loss
 
 
@@ -111,19 +122,28 @@ def hybrid_sgd_step(model, params: Params,
 
     def iteration_loss(p_o: Params, p_s: Params, p_l: Params) -> jax.Array:
         # --- forward phase (Fig. 4 routing) ---
-        h_s = stack.apply_segment(p_s, x_s, 0, m_s) if b_s else None
-        h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
-        if codec is not None and h_s is not None and m_s > 0:
-            h_s = codec(h_s)
-        if codec is not None and h_l is not None and m_l > 0:
-            h_l = codec(h_l)
-        a_o = stack.apply_segment(p_o, x_o, 0, m_s)
+        with obs.scope("hier.stream0"):
+            h_s = stack.apply_segment(p_s, x_s, 0, m_s) if b_s else None
+            if codec is not None and h_s is not None and m_s > 0:
+                h_s = codec(h_s)
+        with obs.scope("hier.stream_l"):
+            h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
+            if codec is not None and h_l is not None and m_l > 0:
+                h_l = codec(h_l)
+        with obs.scope("hier.cloud"):
+            a_o = stack.apply_segment(p_o, x_o, 0, m_s)
         # worker_o continues its own + s's samples through m_s+1..m_l.
-        mid_in = a_o if h_s is None else jnp.concatenate([a_o, h_s], axis=0)
-        mid = stack.apply_segment(p_o, mid_in, m_s, m_l)
-        tail_in = mid if h_l is None else jnp.concatenate([mid, h_l], axis=0)
-        logits = stack.apply_segment(p_o, tail_in, m_l, N)
-        labels = jnp.concatenate([y_o, y_s, y_l], axis=0)
+        with obs.scope("hier.merge"):
+            mid_in = a_o if h_s is None else \
+                jnp.concatenate([a_o, h_s], axis=0)
+        with obs.scope("hier.cloud"):
+            mid = stack.apply_segment(p_o, mid_in, m_s, m_l)
+        with obs.scope("hier.merge"):
+            tail_in = mid if h_l is None else \
+                jnp.concatenate([mid, h_l], axis=0)
+            labels = jnp.concatenate([y_o, y_s, y_l], axis=0)
+        with obs.scope("hier.cloud"):
+            logits = stack.apply_segment(p_o, tail_in, m_l, N)
         return stack.sum_loss(logits, labels)
 
     total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
@@ -132,16 +152,8 @@ def hybrid_sgd_step(model, params: Params,
     # --- weight-update phase: layer-wise gradient exchange ---------------
     # Workers hold per-sample-sum gradients; worker_o aggregates the shared
     # frontend layers and every worker scales by 1/B (exact batch-B SGD).
-    new_params: Params = []
-    for i in range(N):
-        g = g_o[i]
-        if i < m_s and b_s:
-            g = jax.tree.map(jnp.add, g, g_s[i])
-        if i < m_l and b_l:
-            g = jax.tree.map(jnp.add, g, g_l[i])
-        new_params.append(jax.tree.map(
-            lambda p, gg: p - lr * (gg / B), params[i], g))
-    return new_params, total_loss / B
+    return _exchange_update(params, g_o, [g_s], g_l, (m_s,), (b_s,), m_l,
+                            b_l, lr, B), total_loss / B
 
 
 def hybrid_step_from_schedule(model, params: Params,
@@ -215,45 +227,75 @@ def multi_hybrid_sgd_step(model, params: Params,
     def iteration_loss(p_o: Params, p_s: List[Params], p_l: Params
                        ) -> jax.Array:
         # --- forward: every front-end up to its own cut ---
-        h = [stack.apply_segment(p_s[i], s_streams[i][0], 0, m_s[i])
-             if b_s[i] else None for i in range(M)]
-        h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
-        if codec is not None:
-            h = [codec(h[i]) if h[i] is not None and m_s[i] > 0 else h[i]
-                 for i in range(M)]
-            if h_l is not None and m_l > 0:
-                h_l = codec(h_l)
+        h, h_l = _fronts(stack, codec, p_s, s_streams, m_s, b_s, p_l, x_l,
+                         m_l, b_l)
         # worker_o walks its segment list, merging arrivals at their cuts.
         cur = x_o
         prev = 0
         for i in join_order:
             if m_s[i] != prev:
-                cur = stack.apply_segment(p_o, cur, prev, m_s[i])
+                with obs.scope("hier.cloud"):
+                    cur = stack.apply_segment(p_o, cur, prev, m_s[i])
                 prev = m_s[i]
-            cur = jnp.concatenate([cur, h[i]], axis=0)
-        cur = stack.apply_segment(p_o, cur, prev, m_l)
-        if h_l is not None:
-            cur = jnp.concatenate([cur, h_l], axis=0)
-        logits = stack.apply_segment(p_o, cur, m_l, N)
-        labels = jnp.concatenate(
-            [y_o] + [s_streams[i][1] for i in join_order] + [y_l], axis=0)
+            with obs.scope("hier.merge"):
+                cur = jnp.concatenate([cur, h[i]], axis=0)
+        with obs.scope("hier.cloud"):
+            cur = stack.apply_segment(p_o, cur, prev, m_l)
+        with obs.scope("hier.merge"):
+            if h_l is not None:
+                cur = jnp.concatenate([cur, h_l], axis=0)
+            labels = jnp.concatenate(
+                [y_o] + [s_streams[i][1] for i in join_order] + [y_l],
+                axis=0)
+        with obs.scope("hier.cloud"):
+            logits = stack.apply_segment(p_o, cur, m_l, N)
         return stack.sum_loss(logits, labels)
 
     total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
         iteration_loss, argnums=(0, 1, 2))(p_o, p_s, p_l)
 
     # --- weight-update phase: layer-wise gradient exchange ---------------
+    return _exchange_update(params, g_o, g_s, g_l, m_s, b_s, m_l, b_l, lr,
+                            B), total_loss / B
+
+
+def _fronts(stack, codec, p_s: List[Params], s_streams, m_s, b_s,
+            p_l: Params, x_l: jax.Array, m_l: int, b_l: int):
+    """Every TASK-S stream's front segment up to its own cut, and TASK
+    L's up to ``m_l``, each through the wire codec where it crosses a
+    cut above 0.  Returns (per-stream activations, TASK L's)."""
+    h = []
+    for i in range(len(m_s)):
+        with obs.scope(f"hier.stream{i}"):
+            a = stack.apply_segment(p_s[i], s_streams[i][0], 0, m_s[i]) \
+                if b_s[i] else None
+            if codec is not None and a is not None and m_s[i] > 0:
+                a = codec(a)
+        h.append(a)
+    with obs.scope("hier.stream_l"):
+        h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
+        if codec is not None and h_l is not None and m_l > 0:
+            h_l = codec(h_l)
+    return h, h_l
+
+
+def _exchange_update(params: Params, g_o, g_s, g_l, m_s, b_s, m_l: int,
+                     b_l: int, lr: float, B: int) -> Params:
+    """Sum each front layer's per-sample-sum gradients over its copies,
+    then one SGD update scaled by ``1/B`` (exact batch-``B`` SGD)."""
     new_params: Params = []
-    for i in range(N):
+    for i in range(len(params)):
         g = g_o[i]
-        for d in range(M):
-            if i < m_s[d] and b_s[d]:
-                g = jax.tree.map(jnp.add, g, g_s[d][i])
-        if i < m_l and b_l:
-            g = jax.tree.map(jnp.add, g, g_l[i])
-        new_params.append(jax.tree.map(
-            lambda p, gg: p - lr * (gg / B), params[i], g))
-    return new_params, total_loss / B
+        with obs.scope("hier.exchange"):
+            for d in range(len(m_s)):
+                if i < m_s[d] and b_s[d]:
+                    g = jax.tree.map(jnp.add, g, g_s[d][i])
+            if i < m_l and b_l:
+                g = jax.tree.map(jnp.add, g, g_l[i])
+        with obs.scope("hier.update"):
+            new_params.append(jax.tree.map(
+                lambda p, gg: p - lr * (gg / B), params[i], g))
+    return new_params
 
 
 def multi_hybrid_step_from_schedule(model, params: Params,
@@ -337,35 +379,37 @@ def tree_hybrid_sgd_step(model, params: Params,
     def front(p_o: Params, p_s: List[Params], p_l: Params) -> jax.Array:
         """Everything up to the cloud boundary ``m_l``: per-stream
         frontends, per-edge merges, worker_o's walk, TASK L's arrival."""
-        h = [stack.apply_segment(p_s[i], s_streams[i][0], 0, m_s[i])
-             if b_s[i] else None for i in range(M)]
-        h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
-        if codec is not None:
-            h = [codec(h[i]) if h[i] is not None and m_s[i] > 0 else h[i]
-                 for i in range(M)]
-            if h_l is not None and m_l > 0:
-                h_l = codec(h_l)
+        h, h_l = _fronts(stack, codec, p_s, s_streams, m_s, b_s, p_l, x_l,
+                         m_l, b_l)
         cur = x_o
         prev = 0
         for cut, members in groups:
             if cut != prev:
-                cur = stack.apply_segment(p_o, cur, prev, cut)
+                with obs.scope("hier.cloud"):
+                    cur = stack.apply_segment(p_o, cur, prev, cut)
                 prev = cut
-            blk = h[members[0]] if len(members) == 1 else \
-                jnp.concatenate([h[i] for i in members], axis=0)
-            cur = jnp.concatenate([cur, blk], axis=0)
-        cur = stack.apply_segment(p_o, cur, prev, m_l)
-        if h_l is not None:
-            cur = jnp.concatenate([cur, h_l], axis=0)
+            with obs.scope("hier.edge_merge"):
+                blk = h[members[0]] if len(members) == 1 else \
+                    jnp.concatenate([h[i] for i in members], axis=0)
+            with obs.scope("hier.merge"):
+                cur = jnp.concatenate([cur, blk], axis=0)
+        with obs.scope("hier.cloud"):
+            cur = stack.apply_segment(p_o, cur, prev, m_l)
+        with obs.scope("hier.merge"):
+            if h_l is not None:
+                cur = jnp.concatenate([cur, h_l], axis=0)
         return cur
 
-    labels = jnp.concatenate(
-        [y_o] + [s_streams[i][1] for i in join_order] + [y_l], axis=0)
+    with obs.scope("hier.merge"):
+        labels = jnp.concatenate(
+            [y_o] + [s_streams[i][1] for i in join_order] + [y_l], axis=0)
 
     if cloud_mesh is None:
         def iteration_loss(p_o: Params, p_s: List[Params], p_l: Params
                            ) -> jax.Array:
-            logits = stack.apply_segment(p_o, front(p_o, p_s, p_l), m_l, N)
+            with obs.scope("hier.cloud"):
+                logits = stack.apply_segment(p_o, front(p_o, p_s, p_l), m_l,
+                                             N)
             return stack.sum_loss(logits, labels)
 
         total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
@@ -374,17 +418,8 @@ def tree_hybrid_sgd_step(model, params: Params,
         total_loss, g_o, g_s, g_l = _sharded_tail_grads(
             stack, front, labels, p_o, p_s, p_l, m_l, N, B, cloud_mesh)
 
-    new_params: Params = []
-    for i in range(N):
-        g = g_o[i]
-        for d in range(M):
-            if i < m_s[d] and b_s[d]:
-                g = jax.tree.map(jnp.add, g, g_s[d][i])
-        if i < m_l and b_l:
-            g = jax.tree.map(jnp.add, g, g_l[i])
-        new_params.append(jax.tree.map(
-            lambda p, gg: p - lr * (gg / B), params[i], g))
-    return new_params, total_loss / B
+    return _exchange_update(params, g_o, g_s, g_l, m_s, b_s, m_l, b_l, lr,
+                            B), total_loss / B
 
 
 def _sharded_tail_grads(stack, front, labels, p_o: Params,
@@ -419,7 +454,9 @@ def _sharded_tail_grads(stack, front, labels, p_o: Params,
     n_local = B // n_shards
 
     def tail_loss(p_o: Params, cur: jax.Array, lab: jax.Array) -> jax.Array:
-        return stack.sum_loss(stack.apply_segment(p_o, cur, m_l, N), lab)
+        with obs.scope("hier.cloud"):
+            logits = stack.apply_segment(p_o, cur, m_l, N)
+        return stack.sum_loss(logits, lab)
 
     def body(p_o: Params, p_s: List[Params], p_l: Params,
              lab_l: jax.Array):
@@ -432,7 +469,8 @@ def _sharded_tail_grads(stack, front, labels, p_o: Params,
             jnp.zeros_like(cur), gc_l, start, 0)
         g_o, g_s, g_l = front_vjp(g_cur)
         g_o = jax.tree.map(jnp.add, g_o, gp_tail)
-        return jax.lax.psum((loss_l, g_o, g_s, g_l), dp)
+        with obs.scope("hier.tail_psum"):
+            return jax.lax.psum((loss_l, g_o, g_s, g_l), dp)
 
     spec_lab = P(dp, *([None] * (labels.ndim - 1)))
     sharded = jax.shard_map(
@@ -550,6 +588,13 @@ def _cached_step(key: Tuple, model, make: Callable[[], Callable]
     return fn
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under a stable program name: the compiled module, its
+    runs in a profiler trace and its compile events carry it."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def jitted_hybrid_step(model, m_s: int, m_l: int, lr: float,
                        wire: str = "none") -> Callable:
     """A compiled ``(params, batches) -> (new_params, loss)`` hybrid step
@@ -562,7 +607,7 @@ def jitted_hybrid_step(model, m_s: int, m_l: int, lr: float,
         def step(params: Params, batches):
             return hybrid_sgd_step(model, params, batches, m_s, m_l, lr,
                                    wire=wire)
-        return jax.jit(step, donate_argnums=0)
+        return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
     return _cached_step(key, model, make)
 
 
@@ -580,7 +625,7 @@ def jitted_multi_hybrid_step(model, m_s: Sequence[int],
         def step(params: Params, batches):
             return multi_hybrid_sgd_step(model, params, batches, cuts,
                                          m_l, lr, wire=wire)
-        return jax.jit(step, donate_argnums=0)
+        return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
     return _cached_step(key, model, make)
 
 
@@ -604,7 +649,7 @@ def jitted_tree_hybrid_step(model, m_s: Sequence[int], m_l: int, lr: float,
                                         m_l, lr, wire=wire,
                                         stream_edge=edges,
                                         cloud_mesh=cloud_mesh)
-        return jax.jit(step, donate_argnums=0)
+        return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
     return _cached_step(key, model, make)
 
 
@@ -616,7 +661,8 @@ def jitted_reference_step(model, lr: float) -> Callable:
     def make():
         def step(params: Params, x: jax.Array, y: jax.Array):
             return reference_sgd_step(model, params, x, y, lr)
-        return jax.jit(step, donate_argnums=0)
+        return jax.jit(_named(step, obs.REFERENCE_PROGRAM),
+                       donate_argnums=0)
     return _cached_step(key, model, make)
 
 

@@ -117,5 +117,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return o, lse[:, :, 0]

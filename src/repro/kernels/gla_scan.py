@@ -131,6 +131,7 @@ def gla_scan_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(_gla_kernel, normalize=normalize, nc=nc)
     y, S, n = pl.pallas_call(
         kernel,
+        name="gla_scan_fwd",
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, W, dk), lambda b, c: (b, c, 0)),

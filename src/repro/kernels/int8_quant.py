@@ -81,6 +81,7 @@ def quantize_int8(x: jax.Array, noise: jax.Array, *, block_rows: int = 256,
 
     q, scale = pl.pallas_call(
         _quant_kernel,
+        name="int8_quant",
         grid=(Mp // bm, 2, Np // bn),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, p, j: (i, j)),

@@ -8,6 +8,11 @@
 * :func:`quantize_int8` / :func:`dequantize_int8` — unbiased int8
   compression for the tiered gradient sync.
 
+The Pallas calls are named (``flash_attention_fwd``, ``gla_scan_fwd``,
+``int8_quant``) and the backward passes run under the named scopes
+``flash_attention_bwd`` and ``gla_scan_bwd``, so a profiler trace finds
+each kernel's work by name whatever implements it.
+
 On non-TPU backends the kernels run in ``interpret=True`` mode (the
 kernel body executes as traced JAX ops) — numerically identical, which
 is what the oracle tests rely on.
@@ -20,6 +25,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import flash_attention as fa
 from repro.kernels import gla_scan as gs
 from repro.kernels import int8_quant as iq
@@ -54,51 +60,58 @@ def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
         return o, (q, k, v, o, lse)
 
     def bwd(res, do):
-        q, k, v, o, lse = res
-        BH, T, hd = q.shape
-        BKV, S, _ = k.shape
-        rep = BH // BKV
-        bk = pick_block(S, block_k)
-        scale = 1.0 / (hd ** 0.5)
-
-        qf = q.astype(jnp.float32).reshape(BKV, rep, T, hd)
-        dof = do.astype(jnp.float32).reshape(BKV, rep, T, hd)
-        of = o.astype(jnp.float32).reshape(BKV, rep, T, hd)
-        lsef = lse.reshape(BKV, rep, T)
-        delta = jnp.sum(dof * of, axis=-1)             # [BKV, rep, T]
-        kb = k.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
-        vb = v.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
-        qpos = jnp.arange(T)
-
-        def step(dq, xs):
-            kj, vj, j = xs                             # [BKV, bk, hd]
-            kpos = j * bk + jnp.arange(bk)
-            s = jnp.einsum("brth,bkh->brtk", qf, kj) * scale
-            mask = jnp.ones((T, bk), bool)
-            if causal:
-                mask &= qpos[:, None] >= kpos[None, :]
-            if window > 0:
-                mask &= kpos[None, :] > qpos[:, None] - window
-            s = jnp.where(mask[None, None], s, ref.NEG_INF)
-            p = jnp.exp(s - lsef[..., None])           # [BKV, rep, T, bk]
-            dv_j = jnp.einsum("brtk,brth->bkh", p, dof)
-            dp = jnp.einsum("brth,bkh->brtk", dof, vj)
-            ds = p * (dp - delta[..., None])
-            dq = dq + scale * jnp.einsum("brtk,bkh->brth", ds, kj)
-            dk_j = scale * jnp.einsum("brtk,brth->bkh", ds, qf)
-            return dq, (dk_j, dv_j)
-
-        dq0 = jnp.zeros_like(qf)
-        dq, (dk, dv) = jax.lax.scan(
-            step, dq0, (kb.swapaxes(0, 1), vb.swapaxes(0, 1),
-                        jnp.arange(S // bk)))
-        dk = dk.swapaxes(0, 1).reshape(BKV, S, hd)
-        dv = dv.swapaxes(0, 1).reshape(BKV, S, hd)
-        return (dq.reshape(BH, T, hd).astype(q.dtype),
-                dk.astype(k.dtype), dv.astype(v.dtype))
+        with obs.scope("flash_attention_bwd"):
+            return _flash_bwd(res, do, causal, window, block_k)
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def _flash_bwd(res, do, causal: bool, window: int, block_k: int):
+    """Flash-attention backward from the forward's log-sum-exp: one
+    ``lax.scan`` over key blocks, each recomputing its score tile."""
+    q, k, v, o, lse = res
+    BH, T, hd = q.shape
+    BKV, S, _ = k.shape
+    rep = BH // BKV
+    bk = pick_block(S, block_k)
+    scale = 1.0 / (hd ** 0.5)
+
+    qf = q.astype(jnp.float32).reshape(BKV, rep, T, hd)
+    dof = do.astype(jnp.float32).reshape(BKV, rep, T, hd)
+    of = o.astype(jnp.float32).reshape(BKV, rep, T, hd)
+    lsef = lse.reshape(BKV, rep, T)
+    delta = jnp.sum(dof * of, axis=-1)             # [BKV, rep, T]
+    kb = k.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
+    vb = v.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
+    qpos = jnp.arange(T)
+
+    def step(dq, xs):
+        kj, vj, j = xs                             # [BKV, bk, hd]
+        kpos = j * bk + jnp.arange(bk)
+        s = jnp.einsum("brth,bkh->brtk", qf, kj) * scale
+        mask = jnp.ones((T, bk), bool)
+        if causal:
+            mask &= qpos[:, None] >= kpos[None, :]
+        if window > 0:
+            mask &= kpos[None, :] > qpos[:, None] - window
+        s = jnp.where(mask[None, None], s, ref.NEG_INF)
+        p = jnp.exp(s - lsef[..., None])           # [BKV, rep, T, bk]
+        dv_j = jnp.einsum("brtk,brth->bkh", p, dof)
+        dp = jnp.einsum("brth,bkh->brtk", dof, vj)
+        ds = p * (dp - delta[..., None])
+        dq = dq + scale * jnp.einsum("brtk,bkh->brth", ds, kj)
+        dk_j = scale * jnp.einsum("brtk,brth->bkh", ds, qf)
+        return dq, (dk_j, dv_j)
+
+    dq0 = jnp.zeros_like(qf)
+    dq, (dk, dv) = jax.lax.scan(
+        step, dq0, (kb.swapaxes(0, 1), vb.swapaxes(0, 1),
+                    jnp.arange(S // bk)))
+    dk = dk.swapaxes(0, 1).reshape(BKV, S, hd)
+    dv = dv.swapaxes(0, 1).reshape(BKV, S, hd)
+    return (dq.reshape(BH, T, hd).astype(q.dtype),
+            dk.astype(k.dtype), dv.astype(v.dtype))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -146,8 +159,9 @@ def _make_gla(chunk: int, normalize: bool, interpret: bool):
                                     chunk=chunk, normalize=normalize)
             return y[:, :, 0], S[:, 0], n[:, 0]
 
-        _, vjp = jax.vjp(chunked, q, k, v, a)
-        return vjp(cts)
+        with obs.scope("gla_scan_bwd"):
+            _, vjp = jax.vjp(chunked, q, k, v, a)
+            return vjp(cts)
 
     f.defvjp(fwd, bwd)
     return f

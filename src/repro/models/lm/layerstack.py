@@ -63,6 +63,7 @@ from typing import Any, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.layerstack import CutMeta, LayerStack
 from repro.models.lm import ssm as ssm_mod
 from repro.models.lm import xlstm as xlstm_mod
@@ -358,36 +359,39 @@ class LMLayerStack(LayerStack):
 
     def apply_segment(self, params: Params, x: jax.Array, start: int,
                       stop: int) -> jax.Array:
-        cfg = self.cfg
+        """Layers ``start..stop-1``, each under the named scope
+        ``layer{i}.{kind}`` (``repro.obs.scope``)."""
         for i in range(start, stop):
-            spec, p = self._plan[i], params[i]
-            if spec.kind == "embed":
-                x = jnp.take(p["embed"], x, axis=0)
-            elif spec.kind == "head":
-                x = _apply_norm(cfg, p["final_norm"], x) @ p["lm_head"]
-            elif spec.kind in ("attn", "moe"):
-                x = _apply_block(cfg, p, x, spec.window)
-            elif spec.kind == "mamba2":
-                h = _resid_hint(cfg, x)
-                hn = _apply_norm(cfg, p["pre"], h)
-                x = h + ssm_mod.apply_mamba2(p["m"], hn, cfg.ssm,
-                                             use_kernel=cfg.use_gla_kernel)
-            elif spec.kind == "mlstm":
-                h = _resid_hint(cfg, x)
-                hn = _apply_norm(cfg, p["pre"], h)
-                x = h + xlstm_mod.apply_mlstm(p["m"], hn, cfg.xlstm,
-                                              use_kernel=cfg.use_gla_kernel)
-            else:
-                h = _resid_hint(cfg, x)
-                hn = _apply_norm(cfg, p["pre"], h)
-                x = h + xlstm_mod.apply_slstm(p["s"], hn, cfg.xlstm)
+            with obs.scope(f"layer{i}.{self._plan[i].kind}"):
+                x = self._apply_layer(i, params[i], x)
         return x
 
+    def _apply_layer(self, i: int, p, x: jax.Array) -> jax.Array:
+        cfg, kind = self.cfg, self._plan[i].kind
+        if kind == "embed":
+            return jnp.take(p["embed"], x, axis=0)
+        if kind == "head":
+            return _apply_norm(cfg, p["final_norm"], x) @ p["lm_head"]
+        if kind in ("attn", "moe"):
+            return _apply_block(cfg, p, x, self._plan[i].window)
+        h = _resid_hint(cfg, x)
+        hn = _apply_norm(cfg, p["pre"], h)
+        if kind == "mamba2":
+            return h + ssm_mod.apply_mamba2(p["m"], hn, cfg.ssm,
+                                            use_kernel=cfg.use_gla_kernel)
+        if kind == "mlstm":
+            return h + xlstm_mod.apply_mlstm(p["m"], hn, cfg.xlstm,
+                                             use_kernel=cfg.use_gla_kernel)
+        return h + xlstm_mod.apply_slstm(p["s"], hn, cfg.xlstm)
+
     def sum_loss(self, logits: jax.Array, labels: jax.Array) -> jax.Array:
-        """Per-sequence-sum token cross-entropy (f32)."""
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        return jnp.sum(nll)
+        """Per-sequence-sum token cross-entropy (f32), under the named
+        scope ``loss``."""
+        with obs.scope("loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None],
+                                       axis=-1)[..., 0]
+            return jnp.sum(nll)
 
     def dummy_batch(self, key: jax.Array, batch: int
                     ) -> Tuple[jax.Array, jax.Array]:

@@ -16,6 +16,7 @@ imports every test file.  ``ops._interpret`` is steered off inside each
 test because ``jax.default_backend()`` here is the CPU.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,10 @@ def test_flash_forward_zamba_widths(one_chip, on_chip):
     txt = _compile_text(
         lambda q, k, v: ops.flash_attention(q, k, v, causal=True), q, q, q)
     assert "tpu_custom_call" in txt
+    # The call keeps its name in the compiled program, where a profiler
+    # trace's events find it.
+    assert re.search(r"%flash_attention_fwd(\.\d+)? = .*custom-call\(",
+                     txt)
 
 
 def test_flash_backward_zamba_widths(one_chip, on_chip):
@@ -82,6 +87,8 @@ def test_flash_backward_zamba_widths(one_chip, on_chip):
     txt = _compile_text(
         jax.value_and_grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
     assert "tpu_custom_call" in txt
+    assert re.search(r"%while[.\d]* = .* while\(.*op_name=\"[^\"]*"
+                     r"flash_attention_bwd\)*/while\"", txt)
 
 
 @pytest.mark.parametrize("t", [1152, 300])
