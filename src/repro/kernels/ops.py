@@ -1,14 +1,16 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-* :func:`flash_attention` — model-layout GQA flash attention with a
-  memory-O(T * block) chunked backward (consumes the kernel's LSE).
+* :func:`flash_attention` — model-layout GQA flash attention; its
+  backward is two Pallas calls over the blocks the mask can keep
+  (consumes the forward's LSE).
 * :func:`gla_scan` — chunked gated linear recurrence; backward via the
   chunked jnp reference (``models/lm/gla.chunked_gla``), whose saved
   state is per chunk, not per step.
 * :func:`quantize_int8` / :func:`dequantize_int8` — unbiased int8
   compression for the tiered gradient sync.
 
-The Pallas calls are named (``flash_attention_fwd``, ``gla_scan_fwd``,
+The Pallas calls are named (``flash_attention_fwd``,
+``flash_attention_bwd_dkv``, ``flash_attention_bwd_dq``, ``gla_scan_fwd``,
 ``int8_quant``) and the backward passes run under the named scopes
 ``flash_attention_bwd`` and ``gla_scan_bwd``, so a profiler trace finds
 each kernel's work by name whatever implements it.
@@ -29,7 +31,6 @@ from repro import obs
 from repro.kernels import flash_attention as fa
 from repro.kernels import gla_scan as gs
 from repro.kernels import int8_quant as iq
-from repro.kernels import ref
 from repro.kernels.tiling import pick_block
 from repro.models.lm.gla import chunked_gla
 
@@ -60,58 +61,18 @@ def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
         return o, (q, k, v, o, lse)
 
     def bwd(res, do):
+        q, k, v, o, lse = res
         with obs.scope("flash_attention_bwd"):
-            return _flash_bwd(res, do, causal, window, block_k)
+            kept = fa.band(q.shape[1], k.shape[1], block_q, block_k, causal,
+                           window)
+            obs.count_blocks("flash_attention_bwd", int(kept.sum()),
+                             kept.size)
+            return fa.flash_attention_bwd(
+                q, k, v, o, lse, do, causal=causal, window=window,
+                block_q=block_q, block_k=block_k, interpret=interpret)
 
     f.defvjp(fwd, bwd)
     return f
-
-
-def _flash_bwd(res, do, causal: bool, window: int, block_k: int):
-    """Flash-attention backward from the forward's log-sum-exp: one
-    ``lax.scan`` over key blocks, each recomputing its score tile."""
-    q, k, v, o, lse = res
-    BH, T, hd = q.shape
-    BKV, S, _ = k.shape
-    rep = BH // BKV
-    bk = pick_block(S, block_k)
-    scale = 1.0 / (hd ** 0.5)
-
-    qf = q.astype(jnp.float32).reshape(BKV, rep, T, hd)
-    dof = do.astype(jnp.float32).reshape(BKV, rep, T, hd)
-    of = o.astype(jnp.float32).reshape(BKV, rep, T, hd)
-    lsef = lse.reshape(BKV, rep, T)
-    delta = jnp.sum(dof * of, axis=-1)             # [BKV, rep, T]
-    kb = k.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
-    vb = v.astype(jnp.float32).reshape(BKV, S // bk, bk, hd)
-    qpos = jnp.arange(T)
-
-    def step(dq, xs):
-        kj, vj, j = xs                             # [BKV, bk, hd]
-        kpos = j * bk + jnp.arange(bk)
-        s = jnp.einsum("brth,bkh->brtk", qf, kj) * scale
-        mask = jnp.ones((T, bk), bool)
-        if causal:
-            mask &= qpos[:, None] >= kpos[None, :]
-        if window > 0:
-            mask &= kpos[None, :] > qpos[:, None] - window
-        s = jnp.where(mask[None, None], s, ref.NEG_INF)
-        p = jnp.exp(s - lsef[..., None])           # [BKV, rep, T, bk]
-        dv_j = jnp.einsum("brtk,brth->bkh", p, dof)
-        dp = jnp.einsum("brth,bkh->brtk", dof, vj)
-        ds = p * (dp - delta[..., None])
-        dq = dq + scale * jnp.einsum("brtk,bkh->brth", ds, kj)
-        dk_j = scale * jnp.einsum("brtk,brth->bkh", ds, qf)
-        return dq, (dk_j, dv_j)
-
-    dq0 = jnp.zeros_like(qf)
-    dq, (dk, dv) = jax.lax.scan(
-        step, dq0, (kb.swapaxes(0, 1), vb.swapaxes(0, 1),
-                    jnp.arange(S // bk)))
-    dk = dk.swapaxes(0, 1).reshape(BKV, S, hd)
-    dv = dv.swapaxes(0, 1).reshape(BKV, S, hd)
-    return (dq.reshape(BH, T, hd).astype(q.dtype),
-            dk.astype(k.dtype), dv.astype(v.dtype))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -17,6 +17,11 @@
   compile cache), by the event's name.  One ``jax.monitoring`` listener,
   registered at import, keeps it; :func:`snapshot` and :func:`reset`
   read and clear it.
+* :data:`BLOCKS` — per kernel that skips score blocks outside its
+  mask, one ``(visited, total)`` pair per traced call: the blocks its
+  grid works on and all ``T/bq x S/bk`` of them.  Static per shape, so
+  it is recorded while the call is traced; :func:`count_blocks` adds
+  one, :func:`blocks` reads them.
 * :func:`note_step` / :func:`last_step` — the step program last
   dispatched, its arguments' shapes and its token batch's shape, so
   that the compiled text of what ran (each instruction's ``op_name``)
@@ -103,6 +108,19 @@ def reset() -> None:
     COMPILES.done.clear()
     COMPILES._open.clear()
     COMPILES._last = None
+
+
+BLOCKS: Dict[str, List[Tuple[int, int]]] = {}
+
+
+def count_blocks(kernel: str, visited: int, total: int) -> None:
+    BLOCKS.setdefault(kernel, []).append((visited, total))
+
+
+def blocks() -> Dict[str, List[Tuple[int, int]]]:
+    """Per kernel, the ``(visited, total)`` score blocks of each call
+    traced so far, oldest first."""
+    return {k: list(v) for k, v in BLOCKS.items()}
 
 
 # The step program last dispatched: a weak reference to the jitted

@@ -83,21 +83,41 @@ def test_pick_block_is_tpu_legal(n, align, want):
         assert pick_block(n, 512, align) == want
 
 
-def test_flash_grads_match_ref():
-    q, k, v = _qkv(1, 256, 4, 2, 64)
+@pytest.mark.parametrize("B,T,H,KV,hd,causal,window,block,dtype,tol", [
+    (1, 256, 4, 2, 64, True, 32, 128, jnp.float32, 1e-4),
+    (1, 256, 4, 2, 64, True, 0, 128, jnp.float32, 1e-4),     # causal
+    (1, 256, 4, 2, 64, False, 0, 128, jnp.float32, 1e-4),    # every block
+    (1, 256, 4, 2, 64, True, 100, 128, jnp.float32, 1e-4),   # window % block
+    (1, 256, 4, 2, 64, True, 300, 128, jnp.float32, 1e-4),   # window >= T
+    (2, 256, 4, 4, 64, True, 160, 128, jnp.float32, 1e-4),   # rep 1
+    (1, 256, 8, 2, 64, True, 160, 128, jnp.float32, 1e-4),   # rep 4
+    (2, 300, 4, 2, 64, True, 64, 512, jnp.float32, 1e-4),    # one block
+    (1, 1152, 2, 1, 32, True, 500, 512, jnp.float32, 1e-4),  # 384-row
+    (1, 512, 4, 2, 64, True, 200, 128, jnp.bfloat16, 3e-2),
+])
+def test_flash_grads_match_ref(B, T, H, KV, hd, causal, window, block,
+                               dtype, tol):
+    """The backward (its Pallas band kernels in interpret mode) against
+    autodiff of ``attn.mha`` in f32 on the same inputs.  ``block`` is
+    the block target; T = 1152 takes 384-row blocks, T = 300 one."""
+    q, k, v = _qkv(B, T, H, KV, hd, dtype=dtype)
 
     def f_kernel(q, k, v):
-        return (ops.flash_attention(q, k, v, causal=True, window=32,
-                                    block_q=128, block_k=128,
-                                    interpret=True) ** 2).sum()
+        o = ops.flash_attention(q, k, v, causal=causal, window=window,
+                                block_q=block, block_k=block,
+                                interpret=True)
+        return (o.astype(jnp.float32) ** 2).sum()
 
     def f_ref(q, k, v):
-        return (attn.mha(q, k, v, causal=True, window=32) ** 2).sum()
+        return (attn.mha(q, k, v, causal=causal, window=window) ** 2).sum()
 
     g1 = jax.grad(f_kernel, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    up = [x.astype(jnp.float32) for x in (q, k, v)]
+    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(*up)
     for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+        assert a.dtype == dtype
+        np.testing.assert_allclose(a.astype(jnp.float32), b, rtol=tol,
+                                   atol=tol)
 
 
 # ---------------------------------------------------------------------------

@@ -78,8 +78,9 @@ def test_compiled_step_names_layers_phases_and_kernels(compiled_split):
     assert re.search(r"transpose\(jvp\(hier\.cloud\)\)/layer2\.attn/",
                      joined)
     if backend == "pallas":
-        assert re.search(r"transpose\(jvp\(hier\.\w+\)\)/layer\d\.attn/"
-                         r"flash_attention_bwd/while", joined)
+        for call in ("flash_attention_bwd_dkv", "flash_attention_bwd_dq"):
+            assert re.search(r"transpose\(jvp\(hier\.\w+\)\)/layer\d\.attn/"
+                             rf"flash_attention_bwd/{call}/", joined), call
         assert re.search(r"jvp\(hier\.\w+\)/layer\d\.attn/"
                          r"flash_attention_fwd", joined)
     else:
@@ -210,3 +211,59 @@ def test_step_spans_reach_the_profiler_trace(tmp_path):
     for span in ("hiertrain.step", "hiertrain.split_batch",
                  "hiertrain.dispatch"):
         assert names.count(span) == 2, span
+
+
+# Flash-attention backward: the score blocks its grid visits.
+
+def _flash_grad_blocks(B, T, H, KV, causal, window):
+    """The block counts recorded while the gradient of one flash
+    attention call is traced (shapes only: nothing runs)."""
+    import jax.numpy as jnp
+    from repro.kernels import ops
+
+    def loss(q, k, v):
+        return ops.flash_attention(q, k, v, causal=causal, window=window,
+                                   interpret=True).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((B, T, H, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, T, KV, 128), jnp.bfloat16)
+    before = len(obs.blocks().get("flash_attention_bwd", []))
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    return obs.blocks()["flash_attention_bwd"][before:]
+
+
+@pytest.mark.parametrize("causal,window,want", [
+    (True, 2047, (30, 64)),      # the Phi-3 cells: 2047-key window
+    (True, 0, (36, 64)),         # full causal (Zamba2's shared block)
+    (False, 0, (64, 64))])
+def test_flash_backward_counts_the_blocks_it_visits(causal, window, want):
+    got = _flash_grad_blocks(2, 4096, 40, 10, causal, window)
+    assert got == [want]
+
+
+@pytest.mark.parametrize("T,S,bq,bk,causal,window", [
+    (4096, 4096, 512, 512, True, 2047), (4096, 4096, 512, 512, True, 0),
+    (1024, 1024, 128, 128, False, 0), (1024, 1024, 128, 256, True, 300),
+    (1152, 1152, 384, 384, True, 500), (768, 768, 256, 128, True, 2000),
+    (768, 512, 128, 128, True, 129), (512, 512, 128, 128, False, 100),
+    (300, 300, 300, 300, True, 64)])
+def test_flash_band_is_the_blocks_the_mask_keeps(T, S, bq, bk, causal,
+                                                  window):
+    """``band`` against a brute-force count: a block is kept where the
+    mask (the forward's) keeps any of its query-key pairs; the kernels
+    skip the iota mask only where it keeps all of them."""
+    import numpy as np
+    from repro.kernels import flash_attention as fa
+    qpos, kpos = np.arange(T)[:, None], np.arange(S)[None, :]
+    mask = np.ones((T, S), bool)
+    if causal:
+        mask &= qpos >= kpos
+    if window > 0:
+        mask &= kpos > qpos - window
+    blocks = mask.reshape(T // bq, bq, S // bk, bk)
+    np.testing.assert_array_equal(fa.band(T, S, bq, bk, causal, window),
+                                  blocks.any(axis=(1, 3)))
+    i, j = np.arange(T // bq)[:, None], np.arange(S // bk)[None, :]
+    _, every = fa.block_pairs(i, j, bq, bk, causal, window)
+    np.testing.assert_array_equal(
+        np.broadcast_to(every, (T // bq, S // bk)), blocks.all(axis=(1, 3)))

@@ -6,7 +6,8 @@ refuses: unaligned blocks, more VMEM than a kernel may use, Mosaic ops
 with no lowering.  These tests lower each kernel at the widths
 ``chip_smoke.py`` runs — Zamba2-7B (arXiv:2411.15242): attention 32
 heads x 112, Mamba2 112 heads x 64 with chunk 256, d_model 3584,
-T = 4096 — and compile it with the TPU compiler for one chip of a
+T = 4096 — the flash backward also at the benchmark's Phi-3-medium
+widths, and compile it with the TPU compiler for one chip of a
 ``v5e:2x2`` topology.  Nothing runs, so these say nothing about results
 or speed.
 
@@ -15,6 +16,7 @@ only one process may hold the TPU library, and every test worker
 imports every test file.  ``ops._interpret`` is steered off inside each
 test because ``jax.default_backend()`` here is the CPU.
 """
+import functools
 import os
 import re
 
@@ -61,9 +63,22 @@ def _compile_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_loss(q, k, v):
-    return ops.flash_attention(q, k, v, causal=True).astype(
+def _flash_loss(q, k, v, window=0):
+    return ops.flash_attention(q, k, v, causal=True, window=window).astype(
         jnp.float32).sum()
+
+
+def _assert_named_backward(txt: str) -> None:
+    """The backward is the two named Pallas calls under the scope
+    ``flash_attention_bwd``, where a profiler trace finds it; no loop
+    is left under that scope."""
+    for call in ("flash_attention_bwd_dkv", "flash_attention_bwd_dq"):
+        assert re.search(rf'%{call}(\.\d+)? = .*custom-call\(.*'
+                         r'custom_call_target="tpu_custom_call".*'
+                         rf'op_name="[^"]*flash_attention_bwd\)*/{call}/',
+                         txt), call
+    assert not re.search(r'op_name="[^"]*flash_attention_bwd\)*/while',
+                         txt)
 
 
 def _gla_loss(q, k, v, a):
@@ -86,9 +101,18 @@ def test_flash_backward_zamba_widths(one_chip, on_chip):
     q = _spec((B, T, ATTN_HEADS, ATTN_HD), jnp.bfloat16, one_chip)
     txt = _compile_text(
         jax.value_and_grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
-    assert "tpu_custom_call" in txt
-    assert re.search(r"%while[.\d]* = .* while\(.*op_name=\"[^\"]*"
-                     r"flash_attention_bwd\)*/while\"", txt)
+    _assert_named_backward(txt)
+
+
+def test_flash_backward_phi3_widths(one_chip, on_chip):
+    """The benchmark's Phi-3-medium attention: 40 query and 10 KV heads
+    of 128, T = 4096, B = 2, a 2047-key sliding window."""
+    q = _spec((B, T, 40, 128), jnp.bfloat16, one_chip)
+    kv = _spec((B, T, 10, 128), jnp.bfloat16, one_chip)
+    txt = _compile_text(
+        jax.value_and_grad(functools.partial(_flash_loss, window=2047),
+                           argnums=(0, 1, 2)), q, kv, kv)
+    _assert_named_backward(txt)
 
 
 @pytest.mark.parametrize("t", [1152, 300])
