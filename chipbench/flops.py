@@ -4,7 +4,9 @@ The operations the forward and backward passes require: matmul FLOPs
 (2 per multiply-add) of every layer for one sequence, times 3 (the
 backward pass is two matmuls per forward one), times the batch.  Nothing
 recomputed counts, and attention counts only the query-key pairs its
-mask keeps (``pairs``).  Elementwise work rides free.
+mask keeps (``pairs``).  Elementwise work rides free.  The embedding and
+the head are counted here; each kind of layer between them counts its
+own (``chipbench/layers/<kind>.py``).
 
 This is the arithmetic of the program's per-cut metadata
 (``LMLayerStack.cut_meta``), written out again here so that the
@@ -14,6 +16,8 @@ yardstick does not move with the program; the program counts the full
 from __future__ import annotations
 
 from typing import Dict
+
+from chipbench import layers
 
 
 def dims(c: Dict) -> Dict:
@@ -39,22 +43,23 @@ def pairs(T: int, S: int, window: int = 0) -> float:
     return T * S / 2
 
 
-def attn_fwd(d: Dict, T: int) -> float:
-    D, H, KV, hd, F = d["D"], d["H"], d["KV"], d["hd"], d["F"]
-    proj = 2 * T * D * (H * hd) * 2 + 2 * T * D * (KV * hd) * 2
-    scores = 4 * pairs(T, T, d["window"]) * H * hd     # QK^T and AV
-    return float(proj + scores + 3 * 2 * T * D * F)
-
-
 def head_fwd(d: Dict, T: int) -> float:
     return float(2 * T * d["D"] * d["V"])
 
 
-FWD = {"embed": lambda d, T: 0.0, "attn": attn_fwd, "head": head_fwd}
+def layer_flops(c: Dict, kind: str, T: int) -> float:
+    """Forward model FLOPs of one layer of ``kind`` on one sequence: the
+    embedding's lookup needs none, the head its projection to the
+    vocabulary, and every other kind what its module says
+    (``chipbench/layers/<kind>.py``)."""
+    if kind == "embed":
+        return 0.0
+    if kind == "head":
+        return head_fwd(dims(c), T)
+    return layers.load(kind).flops(c, T)
 
 
 def step_flops(c: Dict, T: int, B: int) -> float:
     """Model FLOPs of one training step on a batch of ``B`` sequences of
     ``T`` tokens."""
-    d = dims(c)
-    return 3.0 * B * sum(FWD[k](d, T) for k in d["layers"])
+    return 3.0 * B * sum(layer_flops(c, k, T) for k in c["layers"])

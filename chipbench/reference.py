@@ -6,7 +6,10 @@ of the program under test and takes nothing the program made; it reads
 the weights the benchmark made from the seed (``make_weights``), laid out
 as the program's parameter list (one dict per cut point), and follows
 the layer equations of the published models (``configs/<name>.json``
-lists every departure of the run from them).
+lists every departure of the run from them).  This module holds the
+chain's two ends, the embedding and the head with its loss; each kind
+of layer between them is a module of its own, found by its name
+(``chipbench/layers/<kind>.py``).
 
 A training step here is plain synchronous SGD on the mean over the batch
 of each sequence's summed token cross-entropy, as the program's step is:
@@ -33,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.flops import dims
+from chipbench import layers
 
 F32 = jnp.float32
 E4M3, E5M2 = jnp.float8_e4m3fn, jnp.float8_e5m2
@@ -96,57 +99,8 @@ def rope(x, theta):
 
 
 # ---------------------------------------------------------------------------
-# layers: f(p, h) on one sequence, h: [T, D] float32
+# the chain's ends; the layers between are ``chipbench/layers/<kind>.py``
 # ---------------------------------------------------------------------------
-
-def attention(c: Dict, mode: str, p, h):
-    T = h.shape[0]
-    d = dims(c)
-    H, KV, hd, window = d["H"], d["KV"], d["hd"], d["window"]
-    rep = H // KV
-    q = rope(_mm(mode, "td,de->te", h, p["wq"]).reshape(T, H, hd),
-             c["rope_theta"])
-    k = rope(_mm(mode, "td,de->te", h, p["wk"]).reshape(T, KV, hd),
-             c["rope_theta"])
-    v = _mm(mode, "td,de->te", h, p["wv"]).reshape(T, KV, hd)
-    k = jnp.repeat(k, rep, axis=1)
-    v = jnp.repeat(v, rep, axis=1)
-    nb = T // Q_BLOCK if T % Q_BLOCK == 0 and T > Q_BLOCK else 1
-    bq = T // nb
-
-    @jax.checkpoint
-    def block(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * bq, bq) / np.sqrt(hd)
-        s = _mm(mode, "qhd,khd->hqk", qb, k)
-        qpos = i * bq + jnp.arange(bq)[:, None]
-        kpos = jnp.arange(T)[None, :]
-        keep = qpos >= kpos
-        if window:
-            keep &= kpos > qpos - window
-        s = jnp.where(keep, s, -jnp.inf)
-        return _mm(mode, "hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
-
-    o = jax.lax.map(block, jnp.arange(nb)).reshape(T, H * hd)
-    return _mm(mode, "te,ed->td", o, p["wo"])
-
-
-def mlp(c: Dict, mode: str, p, h):
-    """Gated MLP: silu(h W_gate) * (h W_up) W_down."""
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"unknown hidden_act {c['hidden_act']!r}")
-    g = jax.nn.silu(_mm(mode, "td,df->tf", h, p["w_gate"]))
-    return _mm(mode, "tf,fd->td", g * _mm(mode, "td,df->tf", h,
-                                          p["w_up"]), p["w_down"])
-
-
-def attn_block(c: Dict, mode: str, p, h):
-    eps = c["rms_norm_eps"]
-    h = h + attention(c, mode, p["attn"], rms_norm(h, p["ln1"]["w"], eps))
-    return h + mlp(c, mode, p["mlp"], rms_norm(h, p["ln2"]["w"], eps))
-
-
-LAYERS = {"attn": attn_block}
-
 
 def head_loss(c: Dict, mode: str, p, h, y):
     hn = rms_norm(h, p["final_norm"]["w"], c["rms_norm_eps"])
@@ -177,21 +131,8 @@ def layer_shapes(c: Dict) -> List[Dict]:
         elif kind == "head":
             out.append({"final_norm": {"w": ((D,), bf, "zeros")},
                         "lm_head": ((D, V), bf, "matrix")})
-        elif kind == "attn":
-            d = dims(c)
-            H, KV, hd, F = d["H"], d["KV"], d["hd"], d["F"]
-            out.append({
-                "ln1": {"w": ((D,), bf, "zeros")},
-                "attn": {"wq": ((D, H * hd), bf, "matrix"),
-                         "wk": ((D, KV * hd), bf, "matrix"),
-                         "wv": ((D, KV * hd), bf, "matrix"),
-                         "wo": ((H * hd, D), bf, "matrix")},
-                "ln2": {"w": ((D,), bf, "zeros")},
-                "mlp": {"w_gate": ((D, F), bf, "matrix"),
-                        "w_up": ((D, F), bf, "matrix"),
-                        "w_down": ((F, D), bf, "matrix")}})
         else:
-            raise ValueError(f"unknown layer kind {kind!r}")
+            out.append(layers.load(kind).shapes(c))
     return out
 
 
@@ -199,15 +140,15 @@ def _is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 3 and isinstance(x[1], str)
 
 
-def _init_leaf(c: Dict, key, spec):
+def _init_leaf(key, spec, inits: Dict):
     shape, dtype, init = spec
     if init == "matrix":       # truncated normal, std 1/sqrt(fan_in)
         v = jax.random.truncated_normal(key, -2.0, 2.0, shape, F32) \
             / np.sqrt(shape[0])
     elif init == "zeros":      # norms carry (1 + w)
         v = jnp.zeros(shape, F32)
-    else:
-        raise ValueError(init)
+    else:                      # the layer kind's own
+        v = inits[init](key, shape)
     return v.astype(dtype)
 
 
@@ -217,9 +158,10 @@ def init_layer(c: Dict, key: jax.Array, i: int):
     specs = layer_shapes(c)
     before = sum(len(jax.tree.leaves(s, is_leaf=_is_spec))
                  for s in specs[:i])
+    inits = layers.declared(c["layers"][i], "INIT", {})
     leaves, tree = jax.tree.flatten(specs[i], is_leaf=_is_spec)
     return jax.tree.unflatten(tree, [
-        _init_leaf(c, jax.random.fold_in(key, before + j), s)
+        _init_leaf(jax.random.fold_in(key, before + j), s, inits)
         for j, s in enumerate(leaves)])
 
 
@@ -268,12 +210,13 @@ def _programs(ckey: str, mode: str):
 
     @functools.partial(jax.jit, static_argnums=0)
     def fwd(kind, p, h):
-        return LAYERS[kind](c, mode, _up(p), h)
+        return layers.load(kind).forward(c, mode, _up(p), h)
 
     @functools.partial(jax.jit, static_argnums=0)
     def bwd(kind, p, h, g):
-        _, vjp = jax.vjp(lambda pp, hh: LAYERS[kind](c, mode, pp, hh),
-                         _up(p), h)
+        _, vjp = jax.vjp(
+            lambda pp, hh: layers.load(kind).forward(c, mode, pp, hh),
+            _up(p), h)
         return vjp(g)
 
     @jax.jit

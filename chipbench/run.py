@@ -489,6 +489,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     params, prog = warm_steps(jax, cell, step, params, seed, pool, spans)
 
     rec: Dict[str, Any] = {"chips": cell.chips, "peaks": peaks,
+                           "config": cell.config,
                            "dims": F.dims(cell.config),
                            "flops_per_step": F.step_flops(cell.config, T, B)}
     result: Dict[str, Any] = {}

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from chipbench import flops as F
-from chipbench import run
+from chipbench import layers, run
 from chipbench.kernels import flash_attention_fwd
 from chipbench.tests import tiny
 
@@ -22,10 +22,13 @@ def _hlo_flops(fn, *args) -> float:
                                  .as_text())[0])
 
 
-def _not_in_hlo(kind: str, d, T: int) -> float:
-    if kind == "attn":
-        return -4.0 * (T * T - F.pairs(T, T, d["window"])) * d["H"] * d["hd"]
-    return 0.0
+def _not_in_hlo(config, kind: str, T: int) -> float:
+    """The scores a declared attention's mask throws away."""
+    declare = layers.declared(kind, "attention")
+    if declare is None:
+        return 0.0
+    a = declare(config)
+    return -4.0 * (T * T - F.pairs(T, T, a["window"])) * a["H"] * a["hd"]
 
 
 def _full(config):
@@ -41,13 +44,24 @@ def test_layer_flops_match_compiled(config):
     from repro.models.lm.layerstack import hlo_crosscheck_flops
     cell = tiny.cell(config)
     stack = run.program_stack(cell)
-    d, T = F.dims(config), cell.mix["seq_len"]
+    T = cell.mix["seq_len"]
     for i, kind in enumerate(config["layers"]):
         _, hlo = hlo_crosscheck_flops(stack, i)
-        assert F.FWD[kind](d, T) == pytest.approx(
-            hlo + _not_in_hlo(kind, d, T), rel=1e-12), kind
+        assert F.layer_flops(config, kind, T) == pytest.approx(
+            hlo + _not_in_hlo(config, kind, T), rel=1e-12), kind
     assert F.step_flops(config, T, 2) == 6.0 * sum(
-        F.FWD[k](d, T) for k in config["layers"])
+        F.layer_flops(config, k, T) for k in config["layers"])
+
+
+def test_phi3_step_flops_are_pinned():
+    """The yardstick of ``step_mfu`` in the Phi-3 cells (T 4096, B 2),
+    to the last digit."""
+    cfg = run.load_json(run.ROOT, "chipbench", "configs",
+                        "phi3-medium-6l.json")
+    assert F.layer_flops(cfg, "embed", 4096) == 0.0
+    assert F.layer_flops(cfg, "attn", 4096) == 2920535808000.0
+    assert F.layer_flops(cfg, "head", 4096) == 1344861634560.0
+    assert F.step_flops(cfg, 4096, 2) == 113208458895360.0
 
 
 def _call(outs, ins):

@@ -1,6 +1,8 @@
-"""The harness: every cell resolves to its files by name; a run refuses
-the CPU and a checkout without the program; a run of a tiny cell on the
-CPU (the chip check skipped) is correct and prints the result line."""
+"""The harness: every cell, and every layer kind of every configuration,
+resolves to its files by name; a run refuses the CPU and a checkout
+without the program; a run of a tiny cell on the CPU (the chip check
+skipped) is correct and prints the result line."""
+import glob
 import io
 import json
 import os
@@ -11,7 +13,7 @@ import sys
 import jax
 import pytest
 
-from chipbench import metrics, run
+from chipbench import layers, metrics, run
 from chipbench.tests import tiny
 
 
@@ -41,6 +43,12 @@ def test_every_cell_resolves_to_its_files():
         for key in c["reduced"]:
             assert key in cfg and key in cfg["reduced_from"], key
             assert cfg[key] != cfg["reduced_from"][key], key
+    for path in glob.glob(os.path.join(ROOT, "chipbench", "configs",
+                                       "*.json")):
+        for kind in set(run.load_json(path)["layers"]) - set(layers.ENDS):
+            mod = layers.load(kind)
+            assert all(callable(getattr(mod, f, None))
+                       for f in ("shapes", "forward", "flops")), kind
     names = {m["name"] for m in bench["per_layer"]}
     for w in bench["workloads"]:
         cell = run.load_cell(w["name"])

@@ -142,7 +142,7 @@ def test_step_ops_label_the_step_program_runs_alone():
 def test_flash_backward_need_at_the_phi3_cell():
     cfg = run.load_json(run.ROOT, "chipbench", "configs",
                         "phi3-medium-6l.json")
-    f, b = BWD_MOD.step_cost(F.dims(cfg), 4096, 2, 2)
+    f, b = BWD_MOD.step_cost(cfg, 4096, 2, 2)
     assert F.pairs(4096, 4096, 2047) == 6_289_407.5
     assert f == 8 * 128 * 6_289_407.5 * 40 * 6 * 2
     assert f == pytest.approx(3.0913e12, rel=1e-4)
@@ -150,9 +150,10 @@ def test_flash_backward_need_at_the_phi3_cell():
     assert b / 819e9 < f / 197e12              # FLOP-bound
 
 
-DIMS = {"H": 2, "KV": 1, "hd": 4, "window": 0,
-        "layers": ["embed", "attn", "head"]}
-REC = {"steps_traced": 1, "dims": DIMS,
+CONFIG = {"hidden_size": 8, "vocab_size": 16, "intermediate_size": 8,
+          "num_attention_heads": 2, "num_key_value_heads": 1,
+          "layers": ["embed", "attn", "head"]}         # hd 4, no window
+REC = {"steps_traced": 1, "config": CONFIG,
        "peaks": {"bf16_flops_per_s": 1e6, "hbm_bytes_per_s": 1e6}}
 
 
@@ -162,7 +163,8 @@ def test_flash_backward_roofline_reader(monkeypatch):
     monkeypatch.setattr(scopes, "step_program",
                         lambda: ("text", args, (1, 8)))
     monkeypatch.setattr(scopes, "scope_map", lambda text: SCOPES)
-    f, b = BWD_MOD.step_cost(DIMS, 8, 1, 2)
+    f, b = BWD_MOD.step_cost(CONFIG, 8, 1, 2)
+    assert (f, b) == (8.0 * 4 * 32 * 2, 12 * 8 * 4 * 2 + 2 * 8 * 4)
     assert BWD_MOD.read(REC, tr) == pytest.approx(
         100 * max(f, b) / 1e6 / 3e-3)
     assert BWD_MOD.read({}, tr) is None
@@ -193,8 +195,8 @@ def test_flash_backward_reader_finds_the_programs_own_backward():
     tr = Trace({0: [Event(f"%{bwd[0]} = f32[8] fusion(...)", 1000 * US,
                           2000 * US)]},
                {0: [Event("jit_hiertrain_step(1)", 0, 5000 * US)]}, [])
-    rec = dict(REC, dims=F.dims(tiny.DENSE))
-    f, b = BWD_MOD.step_cost(rec["dims"], 32, 2, 4)     # float32
+    rec = dict(REC, config=tiny.DENSE)
+    f, b = BWD_MOD.step_cost(tiny.DENSE, 32, 2, 4)      # float32
     assert BWD_MOD.read(rec, tr) == pytest.approx(
         100 * max(f, b) / 1e6 / 2e-3)
 
