@@ -1,11 +1,14 @@
 """Chip smoke test: the HierTrain hybrid-parallel training step on TPU.
 
 Drives the main path once through its public entry points, at the
-published widths of Zamba2-7B (arXiv:2411.15242; d_model 3584, 32 heads
-x 112, d_ff 14336, vocab 32000, Mamba2 d_state 64 / head_dim 64 /
-expand 2 / chunk 256) cut to one whole period of its layer pattern
-(``n_layers=6``: embed, 6 mamba2, 1 attention, head; ~0.90 B params),
-with random weights from a seed and ``backend="pallas"`` so both Pallas
+published widths of Zamba2-7B (arXiv:2411.15242; d_model 3584, vocab
+32000, Mamba2 112 heads x 64 in 2 B/C groups, d_state 64, chunk 256;
+the shared block's attention 32 heads x 224 over [h ; embedding], gated
+GELU 14336, adapter rank 128) cut to one whole period of its layer
+pattern, the pipeline stage of published layers 18-23
+(``FULL.variant(n_layers=6, first_layer=18)``: embed, 5 mamba2, 1
+hybrid layer that applies shared block 1, head; 1.05 B params), with
+random weights from a seed and ``backend="pallas"`` so both Pallas
 kernels (flash attention, GLA scan) sit on the path.
 
 One chip (no arguments):
@@ -27,15 +30,11 @@ tree fleet, with the cloud tail data-parallel over a 4-chip mesh
 one chip.
 
 Sizes.  Compile rehearsal for a described v5e (16 GB HBM, 15.75 GiB
-usable) of the one-period step at T = 4096, ``memory_analysis()``:
-
-* B = 2: parameters 1,806,000,128 B (1.68 GiB); split step temporaries
-  10.17 GiB, planned step 9.78 GiB, reference 9.78 GiB.  With the second
-  parameter copy the reference needs: 13.53 GiB.  Fits.
-* B = 4: split step temporaries 13.86 GiB, reference 13.94 GiB; with
-  both parameter copies 17.2 GiB.  Does not fit.
-
-So the one-chip phases run T = 4096, B = 2.  The four-chip phase needs
+usable) of the stage's planned step at T = 4096, B = 2,
+``memory_analysis()``: parameters 1.96 GiB,
+temporaries 8.08 GiB (12.68 without the Mamba mixers' recomputation,
+``layerstack._remat_mixer``).  So the one-chip phases run T = 4096,
+B = 2.  The four-chip phase needs
 B divisible by the 4 data-parallel shards and an unsharded step of the
 same B on one chip, which B = 4 at T = 4096 does not fit, so it runs
 T = 2048, B = 4.
@@ -74,7 +73,7 @@ LR_TRAIN = 1e-4
 # learning rate large enough that the bf16 weight updates span many
 # ulps of the weights they change (the check compares those updates).
 LR_CHECK = 0.05
-FRONT_CUT = 3            # embed + mamba2 #1 + mamba2 #2
+FRONT_CUT = 3            # embed + mamba2 #18 + mamba2 #19
 # Loss: both paths run the same per-sample forward over the same samples
 # in the same order, but the front segment runs at batch B/2 on each
 # stream instead of B, and the compiler may tile and round those bf16
@@ -130,7 +129,7 @@ def _bootstrap():
 def _stack(T: int):
     from repro.configs import zamba2_7b
     from repro.models.lm.layerstack import lm_layerstack
-    cfg = zamba2_7b.FULL.variant(n_layers=6)
+    cfg = zamba2_7b.FULL.variant(n_layers=6, first_layer=18)
     return lm_layerstack(cfg, seq_len=T, backend="pallas")
 
 

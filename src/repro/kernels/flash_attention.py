@@ -92,9 +92,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool, window: int = 0,
                         block_q: int = 512, block_k: int = 512,
-                        interpret: bool = False):
+                        interpret: bool = False, scale=None):
     """q: [BH, T, hd] (head-major); k/v: [BKV, S, hd]; rep = BH//BKV heads
-    per KV head.  Returns (o [BH, T, hd], lse [BH, T])."""
+    per KV head; scores are ``q k^T * scale`` (default ``hd ** -0.5``).
+    Returns (o [BH, T, hd], lse [BH, T])."""
     BH, T, hd = q.shape
     BKV, S, _ = k.shape
     assert BH % BKV == 0
@@ -103,7 +104,8 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(block_k, S)
     assert T % bq == 0 and S % bk == 0, (T, bq, S, bk)
     nq, nk = T // bq, S // bk
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
 
     kernel = functools.partial(_fwd_kernel, causal=causal, window=window,
                                scale=scale, nk=nk)
@@ -283,10 +285,11 @@ def _dq_kernel(first_ref, count_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool,
                         window: int = 0, block_q: int = 512,
-                        block_k: int = 512, interpret: bool = False):
-    """Gradients (dq, dk, dv) of :func:`flash_attention_fwd` at cotangent
-    ``do`` [BH, T, hd], from its output ``o`` and log-sum-exp ``lse``
-    [BH, T].  Scores, probabilities and their gradients are f32; the
+                        block_k: int = 512, interpret: bool = False,
+                        scale=None):
+    """Gradients (dq, dk, dv) of :func:`flash_attention_fwd` (with the
+    same ``scale``) at cotangent ``do`` [BH, T, hd], from its output
+    ``o`` and log-sum-exp ``lse`` [BH, T].  Scores, probabilities and their gradients are f32; the
     MXU takes ``p`` and ``ds`` in the activations' dtype and
     accumulates in f32."""
     BH, T, hd = q.shape
@@ -296,7 +299,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool,
     bk = min(block_k, S)
     assert T % bq == 0 and S % bk == 0, (T, bq, S, bk)
     nq, nk = T // bq, S // bk
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     kept = band(T, S, bq, bk, causal, window)
     q_first, q_count = _spans(kept.T)          # per key block
     k_first, k_count = _spans(kept)            # per query block

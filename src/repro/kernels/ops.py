@@ -45,19 +45,19 @@ def _interpret() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
-                interpret: bool):
+                scale: float, interpret: bool):
     @jax.custom_vjp
     def f(q, k, v):
         o, _ = fa.flash_attention_fwd(q, k, v, causal=causal, window=window,
                                       block_q=block_q, block_k=block_k,
-                                      interpret=interpret)
+                                      interpret=interpret, scale=scale)
         return o
 
     def fwd(q, k, v):
         o, lse = fa.flash_attention_fwd(q, k, v, causal=causal,
                                         window=window, block_q=block_q,
                                         block_k=block_k,
-                                        interpret=interpret)
+                                        interpret=interpret, scale=scale)
         return o, (q, k, v, o, lse)
 
     def bwd(res, do):
@@ -69,7 +69,8 @@ def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
                              kept.size)
             return fa.flash_attention_bwd(
                 q, k, v, o, lse, do, causal=causal, window=window,
-                block_q=block_q, block_k=block_k, interpret=interpret)
+                block_q=block_q, block_k=block_k, interpret=interpret,
+                scale=scale)
 
     f.defvjp(fwd, bwd)
     return f
@@ -78,14 +79,17 @@ def _make_flash(causal: bool, window: int, block_q: int, block_k: int,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Model layout: q [B, T, H, hd]; k/v [B, S, KV, hd] -> [B, T, H, hd]."""
+                    interpret: Optional[bool] = None,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Model layout: q [B, T, H, hd]; k/v [B, S, KV, hd] -> [B, T, H, hd].
+    Scores are ``q k^T * scale``, by default ``hd ** -0.5``."""
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
     interp = _interpret() if interpret is None else interpret
     bq = pick_block(T, block_q)
     bk = pick_block(S, block_k)
-    f = _make_flash(causal, int(window), bq, bk, interp)
+    scale = 1.0 / (hd ** 0.5) if scale is None else float(scale)
+    f = _make_flash(causal, int(window), bq, bk, scale, interp)
     qh = q.swapaxes(1, 2).reshape(B * H, T, hd)
     kh = k.swapaxes(1, 2).reshape(B * KV, S, hd)
     vh = v.swapaxes(1, 2).reshape(B * KV, S, hd)

@@ -81,7 +81,8 @@ def _project_qkv(p: Params, x: jax.Array, kv_src: jax.Array, n_heads: int,
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array,
         causal: bool, window: int = 0,
-        q_offset: int | jax.Array = 0, block_q: int = 0) -> jax.Array:
+        q_offset: int | jax.Array = 0, block_q: int = 0,
+        scale: Optional[float] = None) -> jax.Array:
     """Reference attention.  q: [B,T,H,hd]; k/v: [B,S,KV,hd].
 
     ``window > 0`` = sliding-window (each query sees the previous ``window``
@@ -90,15 +91,17 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
     memory-bounded blocked evaluation (scan over query blocks, rematerialized
     in backward) — required for the 4k/32k shape cells where the full
     ``[B, KV, T, rep, S]`` score tensor would not fit any memory.
+    Scores are ``q k^T * scale``, by default ``hd ** -0.5``.
     """
     if block_q and q.shape[1] > block_q and q.shape[1] % block_q == 0:
         return _mha_blocked(q, k, v, causal=causal, window=window,
-                            block_q=block_q)
+                            block_q=block_q, scale=scale)
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
     rep = H // KV
-    qf = (q.astype(jnp.float32) / jnp.sqrt(hd).astype(jnp.float32)
-          ).reshape(B, T, KV, rep, hd)
+    qf = q.astype(jnp.float32)
+    qf = (qf / jnp.sqrt(hd).astype(jnp.float32) if scale is None
+          else qf * scale).reshape(B, T, KV, rep, hd)
     kf = k.astype(jnp.float32)
     # grouped einsum: no materialized head-repeat of K/V
     logits = jnp.einsum("btkrh,bskh->bktrs", qf, kf)
@@ -116,7 +119,8 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _mha_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
-                 window: int, block_q: int) -> jax.Array:
+                 window: int, block_q: int,
+                 scale: Optional[float] = None) -> jax.Array:
     """Scan over query blocks; each block takes a full softmax row against
     all of K/V (no online accumulation needed).  The block body is
     checkpointed so backward recomputes scores instead of storing them."""
@@ -132,7 +136,7 @@ def _mha_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
         # the *within-block* query rows.
         qi, k2, v2 = _qkv_hints(qi, k, v)
         out = mha(qi, k2, v2, causal=causal, window=window,
-                  q_offset=i * block_q)
+                  q_offset=i * block_q, scale=scale)
         return None, out
 
     _, out = jax.lax.scan(body, None, (qb, jnp.arange(nb)))
@@ -143,7 +147,10 @@ def self_attention(p: Params, x: jax.Array, *, n_heads: int,
                    n_kv_heads: int, head_dim: int, causal: bool,
                    rope_theta: float = 0.0, window: int = 0,
                    positions: Optional[jax.Array] = None,
-                   use_flash: bool = False, block_q: int = 0) -> jax.Array:
+                   use_flash: bool = False, block_q: int = 0,
+                   scale: Optional[float] = None) -> jax.Array:
+    """Self-attention of ``x`` [B, T, d_in] (``d_in`` is ``wq``'s input
+    width); scores are ``q k^T * scale``, by default ``head_dim ** -0.5``."""
     B, T, _ = x.shape
     q, k, v = _project_qkv(p, x, x, n_heads, n_kv_heads, head_dim)
     q, k, v = _qkv_hints(q, k, v)
@@ -153,9 +160,11 @@ def self_attention(p: Params, x: jax.Array, *, n_heads: int,
         k = apply_rope(k, pos, rope_theta)
     if use_flash:
         from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=causal, window=window)
+        out = kops.flash_attention(q, k, v, causal=causal, window=window,
+                                   scale=scale)
     else:
-        out = mha(q, k, v, causal=causal, window=window, block_q=block_q)
+        out = mha(q, k, v, causal=causal, window=window, block_q=block_q,
+                  scale=scale)
     return out.reshape(B, T, n_heads * head_dim) @ p["wo"]
 
 

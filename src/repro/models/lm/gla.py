@@ -59,10 +59,12 @@ def chunked_gla(q: jax.Array, k: jax.Array, v: jax.Array,
     tot = ca[:, :, -1, :]                            # [B,nc,H]
 
     # Intra-chunk quadratic term (per chunk, all chunks at once).
-    # decay matrix D[i,j] = exp(ca_i - ca_j) for j <= i else 0.
+    # decay matrix D[i,j] = exp(ca_i - ca_j) for j <= i else 0.  The mask
+    # goes on the exponent: above the diagonal ca_i - ca_j is positive
+    # and can overflow exp, and 0 * inf is NaN in the backward.
     rel = ca[:, :, :, None, :] - ca[:, :, None, :, :]     # [B,nc,W,W,H]
     causal = jnp.tril(jnp.ones((W, W), bool))
-    D = jnp.where(causal[None, None, :, :, None], jnp.exp(rel), 0.0)
+    D = jnp.exp(jnp.where(causal[None, None, :, :, None], rel, -jnp.inf))
     scores = jnp.einsum("bcihd,bcjhd->bcijh", qf, kf) * D  # [B,nc,W,W,H]
     y_intra = jnp.einsum("bcijh,bcjhd->bcihd", scores, vf)
 

@@ -24,14 +24,28 @@ Families (``block family`` labels used by benchmarks/tests):
 * ``attention`` — ``dense`` decoder blocks (GQA + SwiGLU, local/global
   window pattern preserved per layer).
 * ``moe``       — dense skeleton with routed-MoE MLPs.
-* ``gla``       — ``zamba``-style Mamba2 (SSD) blocks built on the chunked
-  GLA primitive, with an attention block after every
-  ``shared_attn_every``-th Mamba layer.  The cut-point protocol requires
-  *disjoint per-cut params* (frontend copies are sliced as ``params[:m]``
-  and their gradients aggregated per cut), so the recurring attention
-  block is **untied** here — each occurrence is its own cut-point with its
-  own weights.  The adapter is therefore its own reference model: the
-  hybrid-vs-reference exactness suite runs both paths through this stack.
+* ``gla``       — Zamba2: Mamba2 (SSD) layers built on the chunked GLA
+  primitive, ``h + Mamba2(RMSNorm(h))``, and at the hybrid layer ids
+  (``hybrid_layer_ids``, else every ``shared_attn_every``-th) a
+  ``hybrid`` cut point: application ``j`` of shared block
+  ``j % num_mem_blocks`` reads ``[h ; e]`` (``e`` the token embedding),
+  ``u = RMSNorm([h ; e])``, causal attention with RoPE and scale
+  ``(hd / 2) ** -0.5`` from ``u`` to ``o`` (no residual), ``m =
+  RMSNorm(o)``, ``[g | v] = m W_gate_up + (m A_j) B_j`` (the
+  application's own rank-``adapter_rank`` adapter), ``t = (gelu(g) v)
+  W_down L_j`` (its own linear), then its Mamba layer
+  ``h + Mamba2(RMSNorm(h + t))``.  The stack starts at published layer
+  ``first_layer``, so one pipeline stage is a config.  The embedding
+  stream ``e`` rides with ``h`` as one activation, ``[b, T, 2 D] = [h ;
+  e]``, between the embedding (which emits ``[e ; e]``) and the head
+  (which reads ``h``): a cut ships both.  The cut-point protocol
+  requires *disjoint per-cut params* (frontend copies are sliced as
+  ``params[:m]`` and their gradients aggregated per cut), so a shared
+  block applied more than once in one chain is **untied** here — each
+  application is its own cut point with its own copy (summing the
+  copies' gradients is out of scope).  The adapter is therefore its own
+  reference model: the hybrid-vs-reference exactness suite runs both
+  paths through this stack.
 * ``xlstm``     — mLSTM blocks (GLA primitive) with an sLSTM block every
   ``slstm_every``-th position.
 
@@ -65,9 +79,10 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.layerstack import CutMeta, LayerStack
+from repro.models.lm import attention as attn_mod
 from repro.models.lm import ssm as ssm_mod
 from repro.models.lm import xlstm as xlstm_mod
-from repro.models.lm.common import truncated_normal_init
+from repro.models.lm.common import rms_norm, truncated_normal_init
 from repro.models.lm.model import (LMConfig, _apply_block, _apply_norm,
                                    _group_layout, _init_block, _init_norm,
                                    _resid_hint)
@@ -83,8 +98,20 @@ FAMILY_LABELS = {"dense": "attention", "moe": "moe", "zamba": "gla",
 
 @dataclasses.dataclass(frozen=True)
 class _BlockSpec:
-    kind: str          # embed | attn | moe | mamba2 | mlstm | slstm | head
+    kind: str   # embed | attn | moe | mamba2 | hybrid | mlstm | slstm | head
     window: int = 0    # attention window (0 = full) — attn blocks only
+    block: int = 0     # hybrid: the shared block it applies
+
+
+def _hybrid_applications(cfg: LMConfig) -> List[Tuple[int, int]]:
+    """(published layer id, application index) of each hybrid layer of a
+    zamba stack's layers ``first_layer .. first_layer + n_layers - 1``;
+    application ``j`` applies shared block ``j % num_mem_blocks``."""
+    end = cfg.first_layer + cfg.n_layers
+    ids = cfg.hybrid_layer_ids or tuple(
+        i for i in range(end) if i % cfg.shared_attn_every
+        == cfg.shared_attn_every - 1)
+    return [(i, j) for j, i in enumerate(ids) if cfg.first_layer <= i < end]
 
 
 def _block_plan(cfg: LMConfig) -> List[_BlockSpec]:
@@ -106,12 +133,13 @@ def _block_plan(cfg: LMConfig) -> List[_BlockSpec]:
             plan.append(_BlockSpec(kind,
                                    0 if is_global else cfg.sliding_window))
     elif cfg.family == "zamba":
-        assert cfg.ssm is not None and cfg.shared_attn_every > 0
-        g = cfg.shared_attn_every
-        for i in range(cfg.n_layers):
-            plan.append(_BlockSpec("mamba2"))
-            if (i + 1) % g == 0:
-                plan.append(_BlockSpec("attn", cfg.sliding_window))
+        assert cfg.ssm is not None
+        assert cfg.hybrid_layer_ids or cfg.shared_attn_every > 0
+        apps = dict(_hybrid_applications(cfg))
+        for i in range(cfg.first_layer, cfg.first_layer + cfg.n_layers):
+            plan.append(_BlockSpec("mamba2") if i not in apps else
+                        _BlockSpec("hybrid",
+                                   block=apps[i] % cfg.num_mem_blocks))
     else:  # xlstm
         assert cfg.xlstm is not None
         g = cfg.xlstm.slstm_every
@@ -183,15 +211,29 @@ def _mamba2_meta(cfg: LMConfig, T: int) -> Tuple[int, float]:
     D = cfg.d_model
     di = ssm_mod.d_inner(D, sc)
     nh = ssm_mod.n_ssm_heads(D, sc)
-    conv_ch = di + 2 * sc.d_state
-    params = D * (2 * di + 2 * sc.d_state + nh) + sc.d_conv * conv_ch \
+    conv_ch = ssm_mod.conv_channels(D, sc)
+    params = D * (di + conv_ch + nh) + sc.d_conv * conv_ch \
         + conv_ch + 3 * nh + di + di * D + _norm_params(cfg)
     W = min(sc.chunk, T)
-    flops = 2 * T * D * (2 * di + 2 * sc.d_state + nh) \
+    flops = 2 * T * D * (di + conv_ch + nh) \
         + 2 * T * sc.d_conv * conv_ch \
         + _gla_flops(nh, sc.d_state, sc.head_dim, W, T) \
         + 2 * T * di * D
     return params, float(flops)
+
+
+def _hybrid_meta(cfg: LMConfig, T: int) -> Tuple[int, float]:
+    """The shared block over ``[h ; e]`` (full ``T x T`` scores), its
+    adapter and linear, and the hybrid layer's Mamba layer."""
+    D, H, hd, F, r = (cfg.d_model, cfg.n_heads, cfg.hd, cfg.d_ff,
+                      cfg.adapter_rank)
+    A = H * hd
+    params = 3 * 2 * D * A + A * D + 2 * D * F + F * D \
+        + 2 * D + D + r * (D + 2 * F) + D * D
+    flops = 2 * T * (3 * 2 * D * A + A * D + 2 * D * F + F * D
+                     + r * (D + 2 * F) + D * D) + 4 * T * T * A
+    pm, fm = _mamba2_meta(cfg, T)
+    return params + pm, float(flops + fm)
 
 
 def _mlstm_meta(cfg: LMConfig, T: int) -> Tuple[int, float]:
@@ -221,6 +263,96 @@ def _slstm_meta(cfg: LMConfig, T: int) -> Tuple[int, float]:
     # input projection + per-step recurrent matmul + output projection.
     flops = 2 * T * D * 4 * D + 8 * T * D * hd + 2 * T * D * D
     return params, float(flops)
+
+
+# ---------------------------------------------------------------------------
+# Zamba2's layers over the [h ; e] stream.
+# ---------------------------------------------------------------------------
+
+
+def start_stream(e: jax.Array) -> jax.Array:
+    """The embedding's output on a zamba stack: ``[h ; e]`` with ``h =
+    e``."""
+    return jnp.concatenate([e, e], axis=-1)
+
+
+def _init_hybrid(key: jax.Array, cfg: LMConfig) -> Params:
+    D, H, hd, F, r = (cfg.d_model, cfg.n_heads, cfg.hd, cfg.d_ff,
+                      cfg.adapter_rank)
+    kq, kk, kv, ko, kg, kd, kr, kb, kl, km = jax.random.split(key, 10)
+    tn = lambda k, shape: truncated_normal_init(k, shape, 1.0, cfg.dtype)
+    p = {"shared": {
+             "ln_in": {"w": jnp.zeros((2 * D,), cfg.dtype)},
+             "attn": {"wq": tn(kq, (2 * D, H * hd)),
+                      "wk": tn(kk, (2 * D, H * hd)),
+                      "wv": tn(kv, (2 * D, H * hd)),
+                      "wo": tn(ko, (H * hd, D))},
+             "ln_mlp": _init_norm(cfg),
+             "mlp": {"w_gate_up": tn(kg, (D, 2 * F)),
+                     "w_down": tn(kd, (F, D))}},
+         "linear": tn(kl, (D, D)),
+         "pre": _init_norm(cfg),
+         "m": ssm_mod.init_mamba2(km, D, cfg.ssm, cfg.dtype)}
+    if r:
+        p["adapter"] = {"down": tn(kr, (D, r)), "up": tn(kb, (r, 2 * F))}
+    return p
+
+
+def adapt(p: Params, m: jax.Array) -> jax.Array:
+    """The application's own low-rank term of the shared MLP's
+    ``[gate | up]``."""
+    with obs.scope("adapter"):
+        return (m @ p["down"]) @ p["up"]
+
+
+def _shared_block(cfg: LMConfig, p: Params, adapter, x: jax.Array
+                  ) -> jax.Array:
+    """Zamba2's shared transformer block on ``x = [h ; e]``: attention,
+    then the gated-GELU MLP, each after an RMSNorm, neither with a
+    residual of its own."""
+    eps = cfg.rms_norm_eps
+    u = rms_norm(x, p["ln_in"]["w"], eps)
+    o = attn_mod.self_attention(
+        p["attn"], u, n_heads=cfg.n_heads, n_kv_heads=cfg.n_heads,
+        head_dim=cfg.hd, causal=True, rope_theta=cfg.rope_theta,
+        use_flash=cfg.use_flash, block_q=cfg.attn_block_q,
+        scale=(cfg.hd / 2) ** -0.5)
+    m = _apply_norm(cfg, p["ln_mlp"], o)
+    gu = m @ p["mlp"]["w_gate_up"]
+    if adapter is not None:
+        gu = gu + adapt(adapter, m)
+    g, v = jnp.split(gu, 2, axis=-1)
+    g = jax.nn.gelu(g.astype(jnp.float32), approximate=False)
+    return (g.astype(v.dtype) * v) @ p["mlp"]["w_down"]
+
+
+def _apply_zamba(cfg: LMConfig, kind: str, block: int, p: Params,
+                 x: jax.Array) -> jax.Array:
+    """A mamba2 or hybrid layer on ``x = [h ; e]``; ``e`` passes
+    through."""
+    D = cfg.d_model
+    x = _resid_hint(cfg, x)
+    h = x[..., :D]
+    hin = h
+    if kind == "hybrid":
+        with obs.scope(f"shared{block}"):
+            t = _shared_block(cfg, p["shared"], p.get("adapter"), x)
+        hin = h + t @ p["linear"]
+    y = _remat_mixer(lambda pm, u: ssm_mod.apply_mamba2(
+        pm, u, cfg.ssm, use_kernel=cfg.use_gla_kernel,
+        eps=cfg.rms_norm_eps))(p["m"], _apply_norm(cfg, p["pre"], hin))
+    return jnp.concatenate([h + y, x[..., D:]], axis=-1)
+
+
+def _remat_mixer(fn):
+    """The Mamba2 mixer keeps only its matmuls' outputs for the backward
+    and computes the rest again there (conv, gates, the scan's forward,
+    the gated norm): that chain holds several f32 ``[b, T, d_inner]``
+    arrays per layer.  For the stage of Zamba2-7B's layers 18-23 at
+    T = 4096, B = 2 the step's temporaries go from 12.7 to 8.1 GiB of a
+    v5e's 15.75 (compile estimate for a described chip)."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
 
 
 # ---------------------------------------------------------------------------
@@ -272,11 +404,14 @@ class LMLayerStack(LayerStack):
     def cut_meta(self) -> List[CutMeta]:
         cfg, T = self.cfg, self.seq_len
         act_elem = jnp.dtype(cfg.dtype).itemsize
-        hid_elems = float(T * cfg.d_model)
+        # zamba cuts carry [h ; e]: twice the hidden width.
+        hid_elems = float(T * cfg.d_model * (2 if cfg.family == "zamba"
+                                             else 1))
         hid_act = hid_elems * act_elem
         hid_grad = hid_elems * 4                       # f32 gradient wire
         metas: List[CutMeta] = []
-        counts = {k: 0 for k in ("attn", "moe", "mamba2", "mlstm", "slstm")}
+        counts = {k: 0 for k in ("attn", "moe", "mamba2", "hybrid", "mlstm",
+                                  "slstm")}
         for spec in self._plan:
             if spec.kind == "embed":
                 metas.append(CutMeta(
@@ -308,6 +443,8 @@ class LMLayerStack(LayerStack):
                 p, flops = pa + pm + 2 * _norm_params(cfg), fa + fm
             elif spec.kind == "mamba2":
                 p, flops = _mamba2_meta(cfg, T)
+            elif spec.kind == "hybrid":
+                p, flops = _hybrid_meta(cfg, T)
             elif spec.kind == "mlstm":
                 p, flops = _mlstm_meta(cfg, T)
             else:
@@ -345,6 +482,8 @@ class LMLayerStack(LayerStack):
                 params.append({"pre": _init_norm(cfg),
                                "m": ssm_mod.init_mamba2(
                                    k, cfg.d_model, cfg.ssm, cfg.dtype)})
+            elif spec.kind == "hybrid":
+                params.append(_init_hybrid(k, cfg))
             elif spec.kind == "mlstm":
                 params.append({"pre": _init_norm(cfg),
                                "m": xlstm_mod.init_mlstm(
@@ -368,21 +507,26 @@ class LMLayerStack(LayerStack):
 
     def _apply_layer(self, i: int, p, x: jax.Array) -> jax.Array:
         cfg, kind = self.cfg, self._plan[i].kind
+        zamba = cfg.family == "zamba"
         if kind == "embed":
-            return jnp.take(p["embed"], x, axis=0)
+            e = jnp.take(p["embed"], x, axis=0)
+            return start_stream(e) if zamba else e
         if kind == "head":
+            if zamba:
+                x = x[..., :cfg.d_model]
             return _apply_norm(cfg, p["final_norm"], x) @ p["lm_head"]
         if kind in ("attn", "moe"):
             return _apply_block(cfg, p, x, self._plan[i].window)
+        if zamba:
+            return _apply_zamba(cfg, kind, self._plan[i].block, p, x)
         h = _resid_hint(cfg, x)
         hn = _apply_norm(cfg, p["pre"], h)
-        if kind == "mamba2":
-            return h + ssm_mod.apply_mamba2(p["m"], hn, cfg.ssm,
-                                            use_kernel=cfg.use_gla_kernel)
         if kind == "mlstm":
             return h + xlstm_mod.apply_mlstm(p["m"], hn, cfg.xlstm,
-                                             use_kernel=cfg.use_gla_kernel)
-        return h + xlstm_mod.apply_slstm(p["s"], hn, cfg.xlstm)
+                                             use_kernel=cfg.use_gla_kernel,
+                                             eps=cfg.rms_norm_eps)
+        return h + xlstm_mod.apply_slstm(p["s"], hn, cfg.xlstm,
+                                         eps=cfg.rms_norm_eps)
 
     def sum_loss(self, logits: jax.Array, labels: jax.Array) -> jax.Array:
         """Per-sequence-sum token cross-entropy (f32), under the named

@@ -68,9 +68,18 @@ class LMConfig:
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     shared_attn_every: int = 0      # zamba
+    # zamba LayerStack (``layerstack.py``): the published hybrid layers'
+    # ids (default: every ``shared_attn_every``-th), how many shared
+    # blocks they take in turn, the rank of each application's MLP
+    # adapter (0: none), and the published layer the stack starts at.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0
+    first_layer: int = 0
     encoder_layers: int = 0
     n_frontend_tokens: int = 0      # stub prefix length (frames / patches)
     norm: str = "rms"               # rms | layer
+    rms_norm_eps: float = 1e-6
     mlp: str = "swiglu"             # swiglu | geglu | gelu
     dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -116,7 +125,7 @@ def _init_norm(cfg: LMConfig) -> Params:
 def _apply_norm(cfg: LMConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.norm == "layer":
         return layer_norm(x, p["w"], p["b"])
-    return rms_norm(x, p["w"])
+    return rms_norm(x, p["w"], cfg.rms_norm_eps)
 
 
 def _init_mlp(key, cfg: LMConfig) -> Params:
@@ -489,7 +498,8 @@ def _build_zamba(cfg: LMConfig) -> Model:
         x = _resid_hint(cfg, x)
         h = _apply_norm(cfg, lp["pre"], x)
         return x + ssm_mod.apply_mamba2(lp["m"], h, cfg.ssm,
-                                        use_kernel=cfg.use_gla_kernel), None
+                                        use_kernel=cfg.use_gla_kernel,
+                                        eps=cfg.rms_norm_eps), None
 
     def _mamba_stack(x, stacked):
         x, _ = jax.lax.scan(_maybe_remat(cfg, _mamba_body), x, stacked)
@@ -537,7 +547,8 @@ def _build_zamba(cfg: LMConfig) -> Model:
         def m_body(x, lp):
             h = _apply_norm(cfg, lp["pre"], x)
             y, c = ssm_mod.prefill_mamba2(lp["m"], h, cfg.ssm,
-                                          use_kernel=cfg.use_gla_kernel)
+                                          use_kernel=cfg.use_gla_kernel,
+                                          eps=cfg.rms_norm_eps)
             return x + y, c
 
         def group_body(x, gp):
@@ -567,7 +578,8 @@ def _build_zamba(cfg: LMConfig) -> Model:
         def m_body(x, xs):
             lp, lc = xs
             h = _apply_norm(cfg, lp["pre"], x)
-            y, nc = ssm_mod.decode_mamba2(lp["m"], h, lc, cfg.ssm)
+            y, nc = ssm_mod.decode_mamba2(lp["m"], h, lc, cfg.ssm,
+                                          cfg.rms_norm_eps)
             return x + y, nc
 
         def group_body(x, xs):

@@ -79,14 +79,14 @@ def _mlstm_qkv_gates(p: Params, x: jax.Array, cfg: XLSTMConfig,
 
 
 def apply_mlstm(p: Params, x: jax.Array, cfg: XLSTMConfig,
-                use_kernel: bool = False) -> jax.Array:
+                use_kernel: bool = False, eps: float = 1e-6) -> jax.Array:
     B, T, D = x.shape
     di = cfg.expand * D
     q, k, v, log_decay, z, _ = _mlstm_qkv_gates(p, x, cfg)
     y, _ = chunked_gla(q, k, v, log_decay, chunk=cfg.chunk, normalize=True,
                        use_kernel=use_kernel)
     y = y.reshape(B, T, di)
-    y = rms_norm(y, p["norm_w"])
+    y = rms_norm(y, p["norm_w"], eps)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     return y @ p["down_proj"]
 
@@ -170,7 +170,7 @@ def _slstm_cell(carry, gates_x, nh: int, hd: int, r: jax.Array):
 
 
 def _slstm_forward(p: Params, x: jax.Array, cfg: XLSTMConfig,
-                   carry: Optional[Tuple] = None):
+                   carry: Optional[Tuple] = None, eps: float = 1e-6):
     """Strictly sequential over T (lax.scan).  Returns (y, final_carry)."""
     B, T, D = x.shape
     nh = cfg.n_heads
@@ -186,12 +186,13 @@ def _slstm_forward(p: Params, x: jax.Array, cfg: XLSTMConfig,
         lambda carry, g: _slstm_cell(carry, g, nh, hd, p["r"]),
         carry, gx.swapaxes(0, 1))
     y = hs.swapaxes(0, 1).reshape(B, T, D).astype(x.dtype)
-    y = rms_norm(y, p["norm_w"])
+    y = rms_norm(y, p["norm_w"], eps)
     return y @ p["out_proj"], carry
 
 
-def apply_slstm(p: Params, x: jax.Array, cfg: XLSTMConfig) -> jax.Array:
-    y, _ = _slstm_forward(p, x, cfg)
+def apply_slstm(p: Params, x: jax.Array, cfg: XLSTMConfig,
+                eps: float = 1e-6) -> jax.Array:
+    y, _ = _slstm_forward(p, x, cfg, eps=eps)
     return y
 
 

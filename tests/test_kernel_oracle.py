@@ -215,12 +215,12 @@ def _zamba_stacks():
     from repro.models.lm.layerstack import lm_layerstack
     from repro.models.lm.model import LMConfig
     from repro.models.lm.ssm import SSMConfig
-    cfg = LMConfig(name="oracle-zamba", family="zamba", n_layers=2,
+    cfg = LMConfig(name="oracle-zamba", family="zamba", n_layers=4,
                    d_model=64, n_heads=4, n_kv_heads=4, d_ff=128,
                    vocab=512,
                    ssm=SSMConfig(d_state=16, head_dim=16, expand=2,
                                  chunk=32),
-                   shared_attn_every=1, dtype=jnp.float32)
+                   shared_attn_every=2, dtype=jnp.float32)
     from repro.models.lm.layerstack import LMLayerStack
     assert isinstance(lm_layerstack(cfg, 32, "pallas"), LMLayerStack)
     return (lm_layerstack(cfg, seq_len=32, backend="ref"),
@@ -232,7 +232,7 @@ def test_hybrid_step_pallas_matches_ref(m_s, m_l):
     from repro.core.hybrid_step import hybrid_sgd_step
     st_ref, st_pal = _zamba_stacks()
     assert st_pal.cfg.use_flash and st_pal.cfg.use_gla_kernel
-    # N = embed + (mamba2, attn) x 2 + head = 6 cut-points
+    # N = embed + (mamba2, hybrid) x 2 + head = 6 cut-points
     params = st_ref.init(jax.random.PRNGKey(0))
     x, y = st_ref.dummy_batch(jax.random.PRNGKey(1), 9)
     batches = {"o": (x[:3], y[:3]), "s": (x[3:6], y[3:6]),

@@ -83,33 +83,49 @@ def test_pick_block_is_tpu_legal(n, align, want):
         assert pick_block(n, 512, align) == want
 
 
-@pytest.mark.parametrize("B,T,H,KV,hd,causal,window,block,dtype,tol", [
-    (1, 256, 4, 2, 64, True, 32, 128, jnp.float32, 1e-4),
-    (1, 256, 4, 2, 64, True, 0, 128, jnp.float32, 1e-4),     # causal
-    (1, 256, 4, 2, 64, False, 0, 128, jnp.float32, 1e-4),    # every block
-    (1, 256, 4, 2, 64, True, 100, 128, jnp.float32, 1e-4),   # window % block
-    (1, 256, 4, 2, 64, True, 300, 128, jnp.float32, 1e-4),   # window >= T
-    (2, 256, 4, 4, 64, True, 160, 128, jnp.float32, 1e-4),   # rep 1
-    (1, 256, 8, 2, 64, True, 160, 128, jnp.float32, 1e-4),   # rep 4
-    (2, 300, 4, 2, 64, True, 64, 512, jnp.float32, 1e-4),    # one block
-    (1, 1152, 2, 1, 32, True, 500, 512, jnp.float32, 1e-4),  # 384-row
-    (1, 512, 4, 2, 64, True, 200, 128, jnp.bfloat16, 3e-2),
-])
+FLASH_GRAD_CASES = [
+    (1, 256, 4, 2, 64, True, 32, 128, jnp.float32, 1e-4, None),
+    (1, 256, 4, 2, 64, True, 0, 128, jnp.float32, 1e-4, None),   # causal
+    (1, 256, 4, 2, 64, False, 0, 128, jnp.float32, 1e-4, None),  # every
+    (1, 256, 4, 2, 64, True, 100, 128, jnp.float32, 1e-4, None),  # w % b
+    (1, 256, 4, 2, 64, True, 300, 128, jnp.float32, 1e-4, None),  # w >= T
+    (2, 256, 4, 4, 64, True, 160, 128, jnp.float32, 1e-4, None),  # rep 1
+    (1, 256, 8, 2, 64, True, 160, 128, jnp.float32, 1e-4, None),  # rep 4
+    (2, 300, 4, 2, 64, True, 64, 512, jnp.float32, 1e-4, None),  # 1 block
+    (1, 1152, 2, 1, 32, True, 500, 512, jnp.float32, 1e-4, None),  # 384
+    (1, 512, 4, 2, 64, True, 200, 128, jnp.bfloat16, 3e-2, None),
+    # Zamba2's shared attention: heads of 224, scale (224 / 2) ** -1/2.
+    (1, 256, 4, 4, 224, True, 0, 128, jnp.float32, 1e-4, 112 ** -0.5),
+]
+
+
+def _case_id(case) -> str:
+    """pytest's id of a case, the default scale left out of it."""
+    *head, scale = case
+    return "-".join([getattr(v, "__name__", str(v)) for v in head]
+                    + ([] if scale is None else [f"scale{scale:.4g}"]))
+
+
+@pytest.mark.parametrize("B,T,H,KV,hd,causal,window,block,dtype,tol,scale",
+                         FLASH_GRAD_CASES,
+                         ids=[_case_id(c) for c in FLASH_GRAD_CASES])
 def test_flash_grads_match_ref(B, T, H, KV, hd, causal, window, block,
-                               dtype, tol):
+                               dtype, tol, scale):
     """The backward (its Pallas band kernels in interpret mode) against
     autodiff of ``attn.mha`` in f32 on the same inputs.  ``block`` is
-    the block target; T = 1152 takes 384-row blocks, T = 300 one."""
+    the block target; T = 1152 takes 384-row blocks, T = 300 one;
+    ``scale`` None is ``hd ** -0.5``."""
     q, k, v = _qkv(B, T, H, KV, hd, dtype=dtype)
 
     def f_kernel(q, k, v):
         o = ops.flash_attention(q, k, v, causal=causal, window=window,
                                 block_q=block, block_k=block,
-                                interpret=True)
+                                interpret=True, scale=scale)
         return (o.astype(jnp.float32) ** 2).sum()
 
     def f_ref(q, k, v):
-        return (attn.mha(q, k, v, causal=causal, window=window) ** 2).sum()
+        return (attn.mha(q, k, v, causal=causal, window=window,
+                         scale=scale) ** 2).sum()
 
     g1 = jax.grad(f_kernel, argnums=(0, 1, 2))(q, k, v)
     up = [x.astype(jnp.float32) for x in (q, k, v)]
@@ -185,6 +201,40 @@ def test_gla_grads_match_ref():
     g2 = jax.grad(f_ref, argnums=(0, 1, 2, 3))(q, k, v, a)
     for a1, a2 in zip(g1, g2):
         np.testing.assert_allclose(a1, a2, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("normalize", [False, True])
+def test_gla_grads_finite_at_long_decay_spans(normalize):
+    """Log-decays whose sum over a chunk spans 100 and more (Mamba2's A
+    up to 16 times dt): the chunked form's gradients are finite and equal
+    autodiff of the recurrence run step by step (``gla_decode_step``)."""
+    from repro.models.lm.gla import gla_decode_step
+    ks = jax.random.split(KEY, 4)
+    B, T, H, dk, dv, W = 1, 64, 2, 8, 8, 32
+    q = jax.random.normal(ks[0], (B, T, H, dk))
+    k = jax.random.normal(ks[1], (B, T, H, dk)) * 0.3
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    a = -jax.random.uniform(ks[3], (B, T, H), minval=2.0, maxval=8.0)
+    assert float(-a.reshape(B, T // W, W, H).sum(2).max()) >= 100
+
+    def f_chunked(q, k, v, a):
+        y, _ = chunked_gla(q, k, v, a, chunk=W, normalize=normalize)
+        return (y ** 2).sum()
+
+    def f_steps(q, k, v, a):
+        def step(state, xs):
+            y, state = gla_decode_step(*xs, state, normalize=normalize)
+            return state, y
+        state = (jnp.zeros((B, H, dk, dv)), jnp.zeros((B, H, dk)))
+        _, ys = jax.lax.scan(step, state, tuple(
+            t.swapaxes(0, 1) for t in (q, k, v, a)))
+        return (ys ** 2).sum()
+
+    g1 = jax.grad(f_chunked, argnums=(0, 1, 2, 3))(q, k, v, a)
+    g2 = jax.grad(f_steps, argnums=(0, 1, 2, 3))(q, k, v, a)
+    for x1, x2 in zip(g1, g2):
+        assert np.isfinite(np.asarray(x1)).all()
+        np.testing.assert_allclose(x1, x2, rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

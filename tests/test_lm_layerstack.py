@@ -195,6 +195,7 @@ def test_unsupported_families_rejected():
 @pytest.mark.parametrize("family,cut,lo,hi", [
     ("attention", 1, 0.95, 1.05),    # pure dense matmuls: near-exact
     ("gla", 1, 0.9, 1.1),            # mamba2 (chunked GLA) block
+    ("gla", 2, 0.9, 1.1),            # hybrid: shared block + mamba2
     ("xlstm", 1, 0.9, 1.1),          # mLSTM block
     ("moe", 1, 0.6, 1.4),            # capacity-dependent dispatch einsums
 ])
@@ -219,3 +220,58 @@ def test_head_pins_to_stream_end():
     the analytic reason optimal schedules never place m_l = N."""
     metas = _stack("attention").cut_meta()
     assert metas[-1].act_bytes > max(m.act_bytes for m in metas[:-1])
+
+
+def test_zamba_plan_follows_published_hybrid_ids():
+    """Zamba2-7B's 81 layers: a hybrid cut point at each of the 13
+    published hybrid ids, applying shared blocks 0, 1, 0, ... in turn; the
+    stage of layers 18-23 is 5 mamba2 layers and hybrid layer 23
+    (application 3, block 1); every cut between the embedding and the
+    head carries [h ; e], twice the hidden width."""
+    from repro.configs import zamba2_7b
+    full = lm_layerstack(zamba2_7b.FULL, seq_len=4096)
+    hybrids = [(i - 1, s.block) for i, s in enumerate(full._plan)
+               if s.kind == "hybrid"]
+    assert [i for i, _ in hybrids] == list(zamba2_7b.FULL.hybrid_layer_ids)
+    assert [b for _, b in hybrids] == [j % 2 for j in range(13)]
+    assert full.num_layers == 81 + 2
+    stage = lm_layerstack(zamba2_7b.FULL.variant(n_layers=6, first_layer=18),
+                          seq_len=4096)
+    assert [(s.kind, s.block) for s in stage._plan] == [
+        ("embed", 0)] + [("mamba2", 0)] * 5 + [("hybrid", 1), ("head", 0)]
+    metas = stage.cut_meta()
+    assert {m.act_elems for m in metas[:-1]} == {4096 * 2 * 3584}
+    assert sum(m.param_count for m in metas) == 1_050_960_352
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_rms_norm_eps_reaches_every_layer(family):
+    """``rms_norm_eps`` moves every cut point's output but the
+    embedding's; its default leaves the stack as it was."""
+    import dataclasses
+    cfg = CFGS[family]
+    assert cfg.rms_norm_eps == 1e-6
+    stack = lm_layerstack(cfg, seq_len=T)
+    wide = lm_layerstack(dataclasses.replace(cfg, rms_norm_eps=0.5),
+                         seq_len=T)
+    params = stack.init(jax.random.PRNGKey(3))
+    x, _ = stack.dummy_batch(jax.random.PRNGKey(4), 2)
+    for i in range(stack.num_layers):
+        a = stack.apply_segment(params, x, i, i + 1)
+        b = wide.apply_segment(params, x, i, i + 1)
+        assert (i == 0) == bool(jnp.array_equal(a, b)), i
+        x = a
+
+
+def test_mamba2_init_is_mamba2s():
+    """A = exp(A_log) in A_init_range; softplus(dt_bias) log-uniform in
+    [dt_min, dt_max] and above the floor."""
+    from repro.models.lm import ssm
+    sc = SSMConfig(d_state=16, head_dim=16)
+    p = ssm.init_mamba2(jax.random.PRNGKey(0), 1024, sc, jnp.float32)
+    A = np.exp(np.asarray(p["A_log"]))
+    dt = np.asarray(jax.nn.softplus(p["dt_bias"]))
+    assert A.shape == (128,) and 1.0 <= A.min() and A.max() <= 16.0
+    assert A.max() - A.min() > 10
+    assert 1e-3 * (1 - 1e-5) <= dt.min() and dt.max() <= 0.1 * (1 + 1e-5)
+    assert dt.max() / dt.min() > 20

@@ -3,13 +3,13 @@ described, not attached.
 
 Interpret mode (the CPU suite) cannot see what the chip's compiler
 refuses: unaligned blocks, more VMEM than a kernel may use, Mosaic ops
-with no lowering.  These tests lower each kernel at the widths
-``chip_smoke.py`` runs — Zamba2-7B (arXiv:2411.15242): attention 32
-heads x 112, Mamba2 112 heads x 64 with chunk 256, d_model 3584,
-T = 4096 — the flash backward also at the benchmark's Phi-3-medium
-widths, and compile it with the TPU compiler for one chip of a
-``v5e:2x2`` topology.  Nothing runs, so these say nothing about results
-or speed.
+with no lowering.  These tests lower each kernel at the widths the
+benchmark runs — Zamba2-7B (arXiv:2411.15242): shared attention 32
+heads x 224 with softmax scale (224 / 2) ** -1/2, Mamba2 112 heads x 64
+in 2 B/C groups with chunk 256, d_model 3584, T = 4096 — the flash
+backward also at Phi-3-medium's widths, and compile it with the TPU
+compiler for one chip of a ``v5e:2x2`` topology.  Nothing runs, so
+these say nothing about results or speed.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library, and every test worker
@@ -27,7 +27,8 @@ import pytest
 from repro.kernels import ops
 
 B, T = 2, 4096
-ATTN_HEADS, ATTN_HD = 32, 112
+ATTN_HEADS, ATTN_HD = 32, 224
+ATTN_SCALE = (ATTN_HD / 2) ** -0.5
 SSM_HEADS, SSM_D, SSM_CHUNK = 112, 64, 256
 D_MODEL = 3584
 
@@ -63,9 +64,9 @@ def _compile_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _flash_loss(q, k, v, window=0):
-    return ops.flash_attention(q, k, v, causal=True, window=window).astype(
-        jnp.float32).sum()
+def _flash_loss(q, k, v, window=0, scale=None):
+    return ops.flash_attention(q, k, v, causal=True, window=window,
+                               scale=scale).astype(jnp.float32).sum()
 
 
 def _assert_named_backward(txt: str) -> None:
@@ -89,7 +90,8 @@ def _gla_loss(q, k, v, a):
 def test_flash_forward_zamba_widths(one_chip, on_chip):
     q = _spec((B, T, ATTN_HEADS, ATTN_HD), jnp.bfloat16, one_chip)
     txt = _compile_text(
-        lambda q, k, v: ops.flash_attention(q, k, v, causal=True), q, q, q)
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            scale=ATTN_SCALE), q, q, q)
     assert "tpu_custom_call" in txt
     # The call keeps its name in the compiled program, where a profiler
     # trace's events find it.
@@ -100,7 +102,8 @@ def test_flash_forward_zamba_widths(one_chip, on_chip):
 def test_flash_backward_zamba_widths(one_chip, on_chip):
     q = _spec((B, T, ATTN_HEADS, ATTN_HD), jnp.bfloat16, one_chip)
     txt = _compile_text(
-        jax.value_and_grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
+        jax.value_and_grad(functools.partial(_flash_loss, scale=ATTN_SCALE),
+                           argnums=(0, 1, 2)), q, q, q)
     _assert_named_backward(txt)
 
 
@@ -142,6 +145,28 @@ def test_gla_scan_backward_zamba_widths(one_chip, on_chip):
     txt = _compile_text(
         jax.value_and_grad(_gla_loss, argnums=(0, 1, 2, 3)), q, q, q, a)
     assert "tpu_custom_call" in txt
+
+
+def test_mamba2_grouped_zamba_widths(one_chip, on_chip):
+    """A whole Mamba2 mixer, forward and backward, with B and C in 2
+    groups read by 56 heads each: the GLA kernel takes the heads'
+    broadcast q and k."""
+    from repro.configs import zamba2_7b
+    from repro.models.lm import ssm
+    sc = zamba2_7b.FULL.ssm
+    assert (ssm.n_ssm_heads(D_MODEL, sc), sc.head_dim, sc.n_groups) == (
+        SSM_HEADS, SSM_D, 2)
+    p = jax.eval_shape(lambda k: ssm.init_mamba2(k, D_MODEL, sc,
+                                                 jnp.bfloat16),
+                       jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), p)
+    x = _spec((B, T, D_MODEL), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        return ssm.apply_mamba2(p, x, sc, use_kernel=True).astype(
+            jnp.float32).sum()
+    txt = _compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
+    assert re.search(r"%gla_scan_fwd(\.\d+)? = .*custom-call\(", txt)
 
 
 def test_quantize_int8_unaligned_rows(one_chip, on_chip):
