@@ -3,17 +3,19 @@
 * :func:`flash_attention` — model-layout GQA flash attention; its
   backward is two Pallas calls over the blocks the mask can keep
   (consumes the forward's LSE).
-* :func:`gla_scan` — chunked gated linear recurrence; backward via the
-  chunked jnp reference (``models/lm/gla.chunked_gla``), whose saved
-  state is per chunk, not per step.
+* :func:`gla_scan` — chunked gated linear recurrence; its backward is
+  one Pallas call that walks the chunks in reverse (``normalize=False``),
+  or, for the normalized form (xLSTM's mLSTM), the vjp of the chunked jnp
+  reference (``models/lm/gla.chunked_gla``).  Calls with an initial
+  state never reach the kernels.
 * :func:`quantize_int8` / :func:`dequantize_int8` — unbiased int8
   compression for the tiered gradient sync.
 
 The Pallas calls are named (``flash_attention_fwd``,
 ``flash_attention_bwd_dkv``, ``flash_attention_bwd_dq``, ``gla_scan_fwd``,
-``int8_quant``) and the backward passes run under the named scopes
-``flash_attention_bwd`` and ``gla_scan_bwd``, so a profiler trace finds
-each kernel's work by name whatever implements it.
+``gla_scan_bwd``, ``int8_quant``) and the backward passes run under the
+named scopes ``flash_attention_bwd`` and ``gla_scan_bwd``, so a profiler
+trace finds each kernel's work by name whatever implements it.
 
 On non-TPU backends the kernels run in ``interpret=True`` mode (the
 kernel body executes as traced JAX ops) — numerically identical, which
@@ -116,15 +118,21 @@ def _make_gla(chunk: int, normalize: bool, interpret: bool):
 
     def bwd(res, cts):
         q, k, v, a = res
-
-        def chunked(q, k, v, a):
-            # Kernel layout [BH, T, d] as one-head model layout.
-            y, (S, n) = chunked_gla(q[:, :, None], k[:, :, None],
-                                    v[:, :, None], a[:, :, None],
-                                    chunk=chunk, normalize=normalize)
-            return y[:, :, 0], S[:, 0], n[:, 0]
-
+        nc = q.shape[1] // chunk
         with obs.scope("gla_scan_bwd"):
+            if not normalize:
+                obs.count_blocks("gla_scan_bwd", nc, nc)
+                return gs.gla_scan_bwd(q, k, v, a, *cts, chunk=chunk,
+                                       interpret=interpret)
+            obs.count_blocks("gla_scan_bwd", 0, nc)
+
+            def chunked(q, k, v, a):
+                # Kernel layout [BH, T, d] as one-head model layout.
+                y, (S, n) = chunked_gla(q[:, :, None], k[:, :, None],
+                                        v[:, :, None], a[:, :, None],
+                                        chunk=chunk, normalize=normalize)
+                return y[:, :, 0], S[:, 0], n[:, 0]
+
             _, vjp = jax.vjp(chunked, q, k, v, a)
             return vjp(cts)
 

@@ -17,11 +17,13 @@
   compile cache), by the event's name.  One ``jax.monitoring`` listener,
   registered at import, keeps it; :func:`snapshot` and :func:`reset`
   read and clear it.
-* :data:`BLOCKS` — per kernel that skips score blocks outside its
-  mask, one ``(visited, total)`` pair per traced call: the blocks its
-  grid works on and all ``T/bq x S/bk`` of them.  Static per shape, so
-  it is recorded while the call is traced; :func:`count_blocks` adds
-  one, :func:`blocks` reads them.
+* :data:`BLOCKS` — per kernel, one ``(visited, total)`` pair per
+  traced call: for the flash backward, the score blocks its grid works
+  on and all ``T/bq x S/bk`` of them; for the GLA scan backward, the
+  chunks its Pallas kernel handles (none where the jnp backward runs)
+  and all ``T/W`` of them.  Static per shape, so it is recorded while
+  the call is traced; :func:`count_blocks` adds one, :func:`blocks`
+  reads them.
 * :func:`note_step` / :func:`last_step` — the step program last
   dispatched, its arguments' shapes and its token batch's shape, so
   that the compiled text of what ran (each instruction's ``op_name``)
@@ -118,8 +120,8 @@ def count_blocks(kernel: str, visited: int, total: int) -> None:
 
 
 def blocks() -> Dict[str, List[Tuple[int, int]]]:
-    """Per kernel, the ``(visited, total)`` score blocks of each call
-    traced so far, oldest first."""
+    """Per kernel, the ``(visited, total)`` blocks of each call traced
+    so far, oldest first."""
     return {k: list(v) for k, v in BLOCKS.items()}
 
 

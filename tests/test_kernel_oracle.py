@@ -17,6 +17,8 @@ bound at several cuts.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,6 +138,59 @@ def test_gla_scan_oracle(bh, t, dk, dv, chunk, normalize, dtype, seed):
     assert_oracle_close("gla_y", y, y_ref, dt)
     assert_oracle_close("gla_state", S, S_ref, dt)
     assert_oracle_close("gla_state", n, n_ref, dt)
+
+
+# GLA scan backward vs jax.vjp of the chunked jnp reference: the Pallas
+# backward (normalize=False, no initial state) through ``ops.gla_scan``'s
+# custom VJP, in model layout, for the gradients of q, k, v and the
+# log-decay.  Cases: one chunk and several; log-decays whose chunk sum
+# spans 100 and more; nonzero cotangents on the returned S and n; q and
+# k broadcast over heads from 2 groups, as Mamba2 makes them.
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t,chunk,span,state_cts,groups", [
+    (128, 32, False, False, 0),      # several chunks
+    (64, 64, False, False, 0),       # one chunk: T == W
+    (64, 32, True, False, 0),        # long decays
+    (96, 32, False, True, 0),        # cotangents on S and n
+    (64, 32, False, False, 2),       # q, k from 2 groups
+], ids=["chunks", "one-chunk", "long-decay", "state-cts", "groups"])
+def test_gla_scan_bwd_oracle(t, chunk, span, state_cts, groups, dtype):
+    from repro.kernels import ops
+    from repro.models.lm.gla import chunked_gla
+    dt = jnp.dtype(dtype)
+    B, H, dk, dv = 2, 4, 16, 32
+    ks = jax.random.split(jax.random.PRNGKey(t + chunk + groups), 7)
+    G = groups or H
+    qg = jax.random.normal(ks[0], (B, t, G, dk))
+    kg = jax.random.normal(ks[1], (B, t, G, dk)) * 0.3
+    v = jax.random.normal(ks[2], (B, t, H, dv), dt)
+    if span:
+        a = -jax.random.uniform(ks[3], (B, t, H), minval=2.0, maxval=8.0)
+        assert float(-a.reshape(B, t // chunk, chunk, H).sum(2).max()) >= 100
+    else:
+        a = -0.25 * jax.random.uniform(ks[3], (B, t, H)) - 1e-3
+    cts = (jax.random.normal(ks[4], (B, t, H, dv), dt),
+           (jax.random.normal(ks[5], (B, H, dk, dv)) * state_cts,
+            jax.random.normal(ks[6], (B, H, dk)) * state_cts))
+
+    def heads(x):       # group g serves heads [g H/G, (g+1) H/G)
+        return jnp.repeat(x, H // G, axis=2).astype(dt)
+
+    def scan(gla):
+        return lambda qg, kg, v, a: gla(heads(qg), heads(kg), v, a)
+
+    def grads(gla):
+        return jax.jit(lambda *xs: jax.vjp(scan(gla), *xs)[1](cts))(
+            qg, kg, v, a)
+
+    got = grads(functools.partial(ops.gla_scan, chunk=chunk, interpret=True))
+    want = grads(functools.partial(chunked_gla, chunk=chunk))
+    assert got[2].dtype == dt
+    for kind, g, w in zip(("gla_y", "gla_y", "gla_y", "gla_state"), got,
+                          want):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        assert_oracle_close(kind, g, w, dt)
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,8 @@
 swept over shapes and dtypes, plus gradient checks through the custom
 VJPs and the model-integration equivalence (use_flash / use_gla_kernel
 flags flip nothing numerically)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -201,6 +203,39 @@ def test_gla_grads_match_ref():
     g2 = jax.grad(f_ref, argnums=(0, 1, 2, 3))(q, k, v, a)
     for a1, a2 in zip(g1, g2):
         np.testing.assert_allclose(a1, a2, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("normalize,initial,calls", [
+    (False, False, ["gla_scan_fwd", "gla_scan_bwd"]),   # Mamba2 training
+    (True, False, ["gla_scan_fwd"]),     # mLSTM: the jnp backward
+    (False, True, []),                   # chained prefill: all jnp
+    (True, True, [])])
+def test_gla_backward_path(normalize, initial, calls):
+    """Only the unnormalized scan with no initial state takes the Pallas
+    backward; the others differentiate ``chunked_gla`` as they always
+    did, with the same gradients."""
+    import re
+    ks = jax.random.split(KEY, 5)
+    B, T, H, dk, dv = 1, 64, 2, 16, 16
+    q = jax.random.normal(ks[0], (B, T, H, dk))
+    k = jax.random.normal(ks[1], (B, T, H, dk)) * 0.3
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    a = -jax.nn.softplus(jax.random.normal(ks[3], (B, T, H)))
+    state = (jax.random.normal(ks[4], (B, H, dk, dv)),
+             jnp.ones((B, H, dk))) if initial else None
+
+    def loss(gla):
+        def f(q, k, v, a):
+            y, _ = gla(q, k, v, a, chunk=16, normalize=normalize,
+                       initial_state=state)
+            return (y ** 2).sum()
+        return jax.grad(f, argnums=(0, 1, 2, 3))
+
+    kernel = loss(functools.partial(ops.gla_scan, interpret=True))
+    jaxpr = str(jax.make_jaxpr(kernel)(q, k, v, a))
+    assert re.findall(r"name=(gla_scan_\w+)", jaxpr) == calls
+    for g1, g2 in zip(kernel(q, k, v, a), loss(chunked_gla)(q, k, v, a)):
+        np.testing.assert_allclose(g1, g2, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("normalize", [False, True])

@@ -267,3 +267,38 @@ def test_flash_band_is_the_blocks_the_mask_keeps(T, S, bq, bk, causal,
     _, every = fa.block_pairs(i, j, bq, bk, causal, window)
     np.testing.assert_array_equal(
         np.broadcast_to(every, (T // bq, S // bk)), blocks.all(axis=(1, 3)))
+
+
+# GLA scan backward: the chunks the Pallas kernel handles.
+
+def _mamba2_grad(T):
+    import jax.numpy as jnp
+    from repro.models.lm import ssm
+    sc = ssm.SSMConfig(d_state=16, head_dim=16, chunk=32, n_groups=2)
+    p = ssm.init_mamba2(jax.random.PRNGKey(0), 64, sc, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, T, 64), jnp.bfloat16)
+    return (lambda p, x: ssm.apply_mamba2(p, x, sc, use_kernel=True)
+            .astype(jnp.float32).sum()), p, x
+
+
+def _mlstm_grad(T):
+    import jax.numpy as jnp
+    from repro.models.lm import xlstm
+    xc = xlstm.XLSTMConfig(n_heads=2, chunk=32)
+    p = xlstm.init_mlstm(jax.random.PRNGKey(0), 64, xc, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, T, 64), jnp.bfloat16)
+    return (lambda p, x: xlstm.apply_mlstm(p, x, xc, use_kernel=True)
+            .astype(jnp.float32).sum()), p, x
+
+
+@pytest.mark.parametrize("mixer,T,want", [
+    (_mamba2_grad, 128, (4, 4)),     # Mamba2: the Pallas backward
+    (_mamba2_grad, 32, (1, 1)),
+    (_mlstm_grad, 128, (0, 4))])     # mLSTM (normalized): jnp backward
+def test_gla_backward_counts_the_chunks_the_kernel_handles(mixer, T, want):
+    """One record per traced scan backward, as the gradient of a training
+    mixer is traced (shapes only: nothing runs)."""
+    loss, p, x = mixer(T)
+    before = len(obs.blocks().get("gla_scan_bwd", []))
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1)), p, x)
+    assert obs.blocks()["gla_scan_bwd"][before:] == [want]

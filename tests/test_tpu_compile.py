@@ -82,9 +82,14 @@ def _assert_named_backward(txt: str) -> None:
                          txt)
 
 
-def _gla_loss(q, k, v, a):
-    y, _ = ops.gla_scan(q, k, v, a, chunk=SSM_CHUNK)
+def _gla_loss(q, k, v, a, normalize=False):
+    y, _ = ops.gla_scan(q, k, v, a, chunk=SSM_CHUNK, normalize=normalize)
     return y.astype(jnp.float32).sum()
+
+
+GLA_BWD_CALL = re.compile(r'%gla_scan_bwd(\.\d+)? = .*custom-call\(.*'
+                          r'custom_call_target="tpu_custom_call".*'
+                          r'op_name="[^"]*gla_scan_bwd\)*/gla_scan_bwd/')
 
 
 def test_flash_forward_zamba_widths(one_chip, on_chip):
@@ -140,11 +145,26 @@ def test_gla_scan_forward_zamba_widths(one_chip, on_chip, normalize):
 
 
 def test_gla_scan_backward_zamba_widths(one_chip, on_chip):
+    """The backward is the named Pallas call under the scope
+    ``gla_scan_bwd``, where a profiler trace finds it."""
     q = _spec((B, T, SSM_HEADS, SSM_D), jnp.bfloat16, one_chip)
     a = _spec((B, T, SSM_HEADS), jnp.float32, one_chip)
     txt = _compile_text(
         jax.value_and_grad(_gla_loss, argnums=(0, 1, 2, 3)), q, q, q, a)
-    assert "tpu_custom_call" in txt
+    assert GLA_BWD_CALL.search(txt)
+
+
+def test_gla_scan_normalized_backward_stays_jnp(one_chip, on_chip):
+    """The normalized scan (xLSTM's mLSTM) keeps the jnp backward under
+    the scope ``gla_scan_bwd``: no Pallas call of that name."""
+    q = _spec((B, T, SSM_HEADS, SSM_D), jnp.bfloat16, one_chip)
+    a = _spec((B, T, SSM_HEADS), jnp.float32, one_chip)
+    txt = _compile_text(
+        jax.value_and_grad(functools.partial(_gla_loss, normalize=True),
+                           argnums=(0, 1, 2, 3)), q, q, q, a)
+    assert re.search(r"%[\w.]*gla_scan_fwd[\w.]* = .*custom-call\(", txt)
+    assert not re.search(r"%[\w.]*gla_scan_bwd[\w.]* = ", txt)
+    assert re.search(r'op_name="[^"]*gla_scan_bwd\)*/', txt)
 
 
 def test_mamba2_grouped_zamba_widths(one_chip, on_chip):
@@ -167,6 +187,7 @@ def test_mamba2_grouped_zamba_widths(one_chip, on_chip):
             jnp.float32).sum()
     txt = _compile_text(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
     assert re.search(r"%gla_scan_fwd(\.\d+)? = .*custom-call\(", txt)
+    assert GLA_BWD_CALL.search(txt)
 
 
 def test_quantize_int8_unaligned_rows(one_chip, on_chip):
