@@ -29,8 +29,8 @@ import jax.numpy as jnp
 from benchmarks.common import table
 from benchmarks.fig_lm_fleet import BATCH, CONFIGS, M_SWEEP, SEQ_LEN
 from repro.api import Fleet, plan
+from repro.core.cost_model import MultiSchedule, Schedule
 from repro.core.hybrid_step import jitted_hybrid_step, split_batch
-from repro.core.cost_model import Schedule
 from repro.models.lm.layerstack import lm_layerstack
 from repro.models.lm.model import LMConfig
 from repro.models.lm.ssm import SSMConfig
@@ -79,8 +79,9 @@ def _rows() -> List[Dict]:
 
 
 def _exec_rows() -> List[Dict]:
-    sched = Schedule(worker_o="edge", worker_s="device", worker_l="cloud",
-                     m_s=2, m_l=4, b_o=6, b_s=6, b_l=6)
+    sched = MultiSchedule.from_schedule(Schedule(
+        worker_o="edge", worker_s="device", worker_l="cloud",
+        m_s=2, m_l=4, b_o=6, b_s=6, b_l=6))
     key = jax.random.PRNGKey(0)
     rows: List[Dict] = []
     for backend in ("ref", "pallas"):

@@ -19,7 +19,7 @@ One chip (no arguments):
 3. The split step the planner never picks at these widths: half the
    batch on a device stream whose front segment is embed + the first
    two mamba2 layers, merged into the cloud tail
-   (``jitted_multi_hybrid_step`` / ``multi_split_batch``), against
+   (``jitted_hybrid_step`` / ``split_batch``), against
    ``jitted_reference_step`` on the same params and batch.
 4. Every compiled step must contain ``tpu_custom_call`` (kernels did
    not fall back to interpret mode).
@@ -211,9 +211,9 @@ def one_chip(jax) -> None:
     p = plan(stack, Fleet.lm_default(m=2), B)
     sched = p.schedule
     log(f"[plan] {p.fleet.describe()}: {sched.describe()}")
-    _compile(jax, "plan", hs.jitted_multi_hybrid_step(
+    _compile(jax, "plan", hs.jitted_hybrid_step(
         stack, sched.m_s, sched.m_l, LR_TRAIN, wire=p.wire),
-        shapes, hs.multi_split_batch(x, y, sched))
+        shapes, hs.split_batch(x, y, sched))
     step = p.step_fn(lr=LR_TRAIN)
     params = p.init_params(key)
     times, losses = [], []
@@ -250,10 +250,9 @@ def one_chip(jax) -> None:
     # -- 3. split step vs reference ----------------------------------------
     split = _split_schedule(B)
     log(f"[split] fixed schedule {split.describe()}")
-    split_fn = hs.jitted_multi_hybrid_step(stack, split.m_s, split.m_l,
-                                           LR_CHECK)
+    split_fn = hs.jitted_hybrid_step(stack, split.m_s, split.m_l, LR_CHECK)
     ref_fn = hs.jitted_reference_step(stack, LR_CHECK)
-    batches = hs.multi_split_batch(x, y, split)
+    batches = hs.split_batch(x, y, split)
     _compile(jax, "split", split_fn, shapes, batches)
     _compile(jax, "reference", ref_fn, shapes, x, y)
     t0 = time.perf_counter()
@@ -334,13 +333,12 @@ def four_chips(jax) -> None:
         f"schedule {split.describe()} stream edges {edges}")
     mesh = Mesh(np.array(devs), ("data",), axis_types=(AxisType.Auto,))
     repl = NamedSharding(mesh, P())
-    batches = hs.multi_split_batch(x, y, split)
+    batches = hs.split_batch(x, y, split)
 
-    sharded = hs.jitted_tree_hybrid_step(stack, split.m_s, split.m_l,
-                                         LR_CHECK, stream_edge=edges,
-                                         cloud_mesh=mesh)
-    plain = hs.jitted_tree_hybrid_step(stack, split.m_s, split.m_l,
-                                       LR_CHECK, stream_edge=edges)
+    sharded = hs.jitted_hybrid_step(stack, split.m_s, split.m_l, LR_CHECK,
+                                    stream_edge=edges, cloud_mesh=mesh)
+    plain = hs.jitted_hybrid_step(stack, split.m_s, split.m_l, LR_CHECK,
+                                  stream_edge=edges)
     shapes = jax.eval_shape(stack.init, key)
     params_mesh = jax.device_put(stack.init(key), repl)
     batches_mesh = jax.device_put(batches, repl)

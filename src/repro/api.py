@@ -18,9 +18,11 @@ it:
 The classic (device, edge, cloud) triple is exactly a :class:`Fleet` at
 ``M = 1``; a heterogeneous M-device star is the same call with ``m >= 2``
 (or any custom :class:`Fleet`).  ``plan`` resolves to the topology-native
-engine — bit-for-bit identical across topologies at M = 1 for the
-latency objective — and the returned :class:`Plan` carries the chosen
-schedule, the predicted ``t_total``/``t_period``, and executable methods.
+planner (solver, cost model, DES) — bit-for-bit identical across
+topologies at M = 1 for the latency objective — and the returned
+:class:`Plan` carries the chosen schedule, the predicted
+``t_total``/``t_period``, and executable methods.  Execution has no
+topology fork: every fleet runs the one hybrid step (DESIGN.md §3).
 
 Every pre-facade entry point (``solve``/``solve_multi``, ``t_total*``,
 ``simulate_iteration*``, ``run_*_hier_loop``) survives as a thin
@@ -156,19 +158,22 @@ class Plan:
         return self.model
 
     def stream_edges(self) -> tuple:
-        """Per-TASK-S-stream hosting edge (tree fleets): a device stream
-        sits under its radio's edge, an edge's own stream under itself,
-        and a cloud-hosted stream merges with the front group (index 0 —
-        on an E=1 tree every stream maps to edge 0, which is what keeps
-        the traced step identical to the star's)."""
+        """Per-TASK-S-stream hosting edge: on a tree a device stream sits
+        under its radio's edge, an edge's own stream under itself, and a
+        cloud-hosted stream merges with the front group (index 0); off
+        the tree every stream is on edge 0."""
+        ms = self.multi_schedule
+        if self.fleet.topology != TREE:
+            return (0,) * len(ms.m_s)
         from repro.core.hybrid_step import tree_stream_edges
-        return tree_stream_edges(self.profile, self.network,
-                                 self.multi_schedule)
+        return tree_stream_edges(self.profile, self.network, ms)
 
     def step_fn(self, lr: float = 0.05, cloud_mesh=None) -> Callable:
         """A compiled ``(params, x, y) -> (new_params, loss)`` hybrid-SGD
         step for the chosen schedule (exact batch-B SGD semantics;
-        ``params`` donated, executables cached per cut tuple).
+        ``params`` donated, executables cached per cut tuple).  Every
+        fleet runs the one step (``hybrid_step.hybrid_sgd_step``) on
+        :attr:`multi_schedule` and :meth:`stream_edges`.
 
         ``cloud_mesh`` (tree fleets only) runs the cloud tail segment
         data-parallel over the mesh's dp axes via ``shard_map``
@@ -186,31 +191,20 @@ class Plan:
         from repro.core import hybrid_step as hs
 
         stack = self._require_model()
-        sched = self.schedule
         if cloud_mesh is not None and self.fleet.topology != TREE:
             raise ValueError("cloud_mesh is a tree-topology option; this "
                              f"plan's fleet is {self.fleet.topology!r}")
-        if self.fleet.topology == TRIPLE:
-            fn = hs.jitted_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                       wire=self.wire)
-            split = hs.split_batch
-        elif self.fleet.topology == TREE:
-            fn = hs.jitted_tree_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                            wire=self.wire,
-                                            stream_edge=self.stream_edges(),
-                                            cloud_mesh=cloud_mesh)
-            split = hs.multi_split_batch
-        else:
-            fn = hs.jitted_multi_hybrid_step(stack, sched.m_s, sched.m_l, lr,
-                                             wire=self.wire)
-            split = hs.multi_split_batch
+        ms = self.multi_schedule
+        fn = hs.jitted_hybrid_step(stack, ms.m_s, ms.m_l, lr, wire=self.wire,
+                                   stream_edge=self.stream_edges(),
+                                   cloud_mesh=cloud_mesh)
         numbers = itertools.count()
         noted = []
 
         def step(params, x, y):
             with obs.step_span(next(numbers)):
                 with obs.span("split_batch"):
-                    b = split(jnp.asarray(x), jnp.asarray(y), sched)
+                    b = hs.split_batch(jnp.asarray(x), jnp.asarray(y), ms)
                 if noted != [np.shape(x)]:
                     obs.note_step(fn, (params, b), np.shape(x))
                     noted[:] = [np.shape(x)]

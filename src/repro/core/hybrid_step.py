@@ -5,16 +5,14 @@ holding *separate copies* of their assigned layers, activations crossing at
 the cut points, and only frontend gradients being exchanged — and produces
 the *same* update as vanilla SGD over the full batch ``B`` (sample-weighted
 gradient averaging; see DESIGN.md §3 for why weighting is required for
-exactness).  Two entry points:
+exactness).  One entry point, :func:`hybrid_sgd_step`, serves every
+fleet (DESIGN.md §3): M TASK-S streams with per-stream cuts ``m_s[i]``
+under E edge servers (``stream_edge``), one TASK L, one TASK O.  The
+star is the tree with every stream on edge 0, and the paper's
+three-worker triple is the star at ``M = 1``
+(``MultiSchedule.from_schedule``).
 
-* :func:`hybrid_sgd_step` — the paper's three-worker topology (one TASK S,
-  one TASK L, one TASK O).
-* :func:`multi_hybrid_sgd_step` — M TASK-S streams with per-stream cuts
-  ``m_s[i]`` (DESIGN.md §6); worker_o picks each arriving stream up at its
-  own cut, in ascending-cut order.  With ``M = 1`` the traced program is
-  identical to :func:`hybrid_sgd_step`.
-
-Every entry point is model-agnostic (DESIGN.md §8): it takes anything
+The step is model-agnostic (DESIGN.md §8): it takes anything
 :func:`repro.core.layerstack.as_layerstack` accepts — a bare
 :class:`repro.models.cnn.LayeredModel` (traced bit-identically to the
 pre-adapter code) or an adapter such as the LM model-zoo stack.  The stack
@@ -23,17 +21,19 @@ pytree per cut-point, ``apply_segment`` runs a contiguous cut range, and
 ``sum_loss`` is the per-sample-*sum* objective (so one division by ``B``
 yields exact batch-B SGD).
 
-The three-worker forward routing (Fig. 4):
+The forward routing (Fig. 4, one TASK-S stream per non-o, non-l worker):
 
-* ``worker_s``: layers ``1..m_s`` on its ``b_s`` samples -> ships ``h_s``.
+* stream ``i``: layers ``1..m_s[i]`` on its ``b_s[i]`` samples -> ships
+  ``h_i``; streams sharing ``(cut, edge)`` are concatenated on the edge.
 * ``worker_l``: layers ``1..m_l`` on its ``b_l`` samples -> ships ``h_l``.
-* ``worker_o``: layers ``1..m_s`` on ``b_o``; layers ``m_s+1..m_l`` on its own
-  activations *plus the arrived* ``h_s``; layers ``m_l+1..N`` on everything.
+* ``worker_o``: walks layers ``1..m_l`` on its own samples, merging each
+  arriving block at its cut in ascending-cut order, then takes ``h_l``
+  at ``m_l`` and runs layers ``m_l+1..N`` on everything.
 
 The backward pass retraces this routing (handled by AD through the composed
-function — gradients w.r.t. ``params_s`` are exactly what worker_s computes
-after receiving the intermediate result at layer ``m_s+1``).  Weight update:
-per-layer gradient exchange over the *shared* frontend only.
+function — gradients w.r.t. stream ``i``'s copy are exactly what its worker
+computes after receiving the intermediate result at layer ``m_s[i]+1``).
+Weight update: per-layer gradient exchange over the *shared* frontend only.
 
 Every phase runs under a named scope (``repro.obs.scope``), so each
 operation of the compiled step names the phase it belongs to:
@@ -77,104 +77,8 @@ def reference_sgd_step(model, params: Params, x: jax.Array,
     return new, loss
 
 
-def split_batch(x: jax.Array, y: jax.Array, sched: Schedule
-                ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
-    """Assign the first b_o samples to o, next b_s to s, rest to l."""
-    bo, bs, bl = sched.b_o, sched.b_s, sched.b_l
-    assert bo + bs + bl == x.shape[0]
-    return {
-        "o": (x[:bo], y[:bo]),
-        "s": (x[bo:bo + bs], y[bo:bo + bs]),
-        "l": (x[bo + bs:], y[bo + bs:]),
-    }
-
-
-def hybrid_sgd_step(model, params: Params,
-                    batches: Dict[str, Tuple[jax.Array, jax.Array]],
-                    m_s: int, m_l: int, lr: float, wire: str = "none"
-                    ) -> Tuple[Params, jax.Array]:
-    """One HierTrain iteration.  Returns (updated params, mean loss).
-
-    ``params`` plays the role of the consensus weights each worker starts
-    the iteration with (they are equal after every weight-update phase).
-
-    ``wire`` selects the cut-point transfer codec (``repro.core.wire``):
-    ``"int8"`` quantizes the shipped activations forward and — via the
-    codec's custom VJP — the returning activation-gradients backward;
-    ``"none"`` leaves the traced program bit-identical to the seed.  A
-    cut at 0 is a raw-input upload (the ``sample_bytes`` channel), so
-    the codec only touches crossings with ``m > 0``.
-    """
-    stack = as_layerstack(model)
-    N = stack.num_layers
-    assert 0 <= m_s <= m_l <= N
-    codec = wire_codec(wire)
-    x_o, y_o = batches["o"]
-    x_s, y_s = batches["s"]
-    x_l, y_l = batches["l"]
-    b_o, b_s, b_l = x_o.shape[0], x_s.shape[0], x_l.shape[0]
-    B = b_o + b_s + b_l
-
-    # Worker-local copies: p_s = frontend 1..m_s, p_l = 1..m_l, p_o = all.
-    p_o = params
-    p_s = params[:m_s]
-    p_l = params[:m_l]
-
-    def iteration_loss(p_o: Params, p_s: Params, p_l: Params) -> jax.Array:
-        # --- forward phase (Fig. 4 routing) ---
-        with obs.scope("hier.stream0"):
-            h_s = stack.apply_segment(p_s, x_s, 0, m_s) if b_s else None
-            if codec is not None and h_s is not None and m_s > 0:
-                h_s = codec(h_s)
-        with obs.scope("hier.stream_l"):
-            h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
-            if codec is not None and h_l is not None and m_l > 0:
-                h_l = codec(h_l)
-        with obs.scope("hier.cloud"):
-            a_o = stack.apply_segment(p_o, x_o, 0, m_s)
-        # worker_o continues its own + s's samples through m_s+1..m_l.
-        with obs.scope("hier.merge"):
-            mid_in = a_o if h_s is None else \
-                jnp.concatenate([a_o, h_s], axis=0)
-        with obs.scope("hier.cloud"):
-            mid = stack.apply_segment(p_o, mid_in, m_s, m_l)
-        with obs.scope("hier.merge"):
-            tail_in = mid if h_l is None else \
-                jnp.concatenate([mid, h_l], axis=0)
-            labels = jnp.concatenate([y_o, y_s, y_l], axis=0)
-        with obs.scope("hier.cloud"):
-            logits = stack.apply_segment(p_o, tail_in, m_l, N)
-        return stack.sum_loss(logits, labels)
-
-    total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
-        iteration_loss, argnums=(0, 1, 2))(p_o, p_s, p_l)
-
-    # --- weight-update phase: layer-wise gradient exchange ---------------
-    # Workers hold per-sample-sum gradients; worker_o aggregates the shared
-    # frontend layers and every worker scales by 1/B (exact batch-B SGD).
-    return _exchange_update(params, g_o, [g_s], g_l, (m_s,), (b_s,), m_l,
-                            b_l, lr, B), total_loss / B
-
-
-def hybrid_step_from_schedule(model, params: Params,
-                              x: jax.Array, y: jax.Array, sched: Schedule,
-                              lr: float, wire: str = "none"
-                              ) -> Tuple[Params, jax.Array]:
-    return hybrid_sgd_step(model, params, split_batch(x, y, sched),
-                           sched.m_s, sched.m_l, lr, wire=wire)
-
-
-# ---------------------------------------------------------------------------
-# M-stream generalization (DESIGN.md §6): one TASK-S instance per non-o,
-# non-l worker, each with its own cut.  worker_o merges stream i into its
-# running activation batch at layer m_s[i] (ascending-cut order, stream
-# index breaking ties), then TASK L's stream at m_l, exactly mirroring the
-# generalized cost model's routing.
-# ---------------------------------------------------------------------------
-
-
-def multi_split_batch(x: jax.Array, y: jax.Array, sched: MultiSchedule
-                      ) -> Dict[str, object]:
+def split_batch(x: jax.Array, y: jax.Array, sched: MultiSchedule
+                ) -> Dict[str, object]:
     """Assign the first ``b_o`` samples to o, the next ``b_s[i]`` to each
     TASK-S stream in ``s_workers`` order, and the remainder to l."""
     bo, bl = sched.b_o, sched.b_l
@@ -190,157 +94,38 @@ def multi_split_batch(x: jax.Array, y: jax.Array, sched: MultiSchedule
     return out
 
 
-def multi_hybrid_sgd_step(model, params: Params,
-                          batches: Dict[str, object],
-                          m_s: Sequence[int], m_l: int, lr: float,
-                          wire: str = "none"
-                          ) -> Tuple[Params, jax.Array]:
-    """One M-stream HierTrain iteration.  Returns (updated params, mean
-    loss).  Exact batch-``B`` SGD semantics: per-stream gradients are
-    per-sample sums, aggregated over every copy of each frontend layer and
-    scaled once by ``1/B``.  With ``M = 1`` and the same schedule this
-    traces the identical program to :func:`hybrid_sgd_step` (including
-    the ``wire`` codec, applied per arriving stream at its cut).
-    """
-    stack = as_layerstack(model)
-    N = stack.num_layers
-    codec = wire_codec(wire)
-    m_s = tuple(int(m) for m in m_s)
-    M = len(m_s)
-    x_o, y_o = batches["o"]
-    s_streams = batches["s"]
-    x_l, y_l = batches["l"]
-    assert len(s_streams) == M
-    assert all(0 <= m <= m_l for m in m_s) and m_l <= N
-    b_s = [sx.shape[0] for sx, _ in s_streams]
-    b_o, b_l = x_o.shape[0], x_l.shape[0]
-    B = b_o + sum(b_s) + b_l
-    # Streams join worker_o's batch in ascending-cut order (stream index
-    # breaks ties) — the labels must concatenate in the same order.
-    join_order = sorted((i for i in range(M) if b_s[i]),
-                        key=lambda i: (m_s[i], i))
+def hybrid_sgd_step(model, params: Params, batches: Dict[str, object],
+                    m_s: Sequence[int], m_l: int, lr: float,
+                    wire: str = "none",
+                    stream_edge: Sequence[int] | None = None,
+                    cloud_mesh=None) -> Tuple[Params, jax.Array]:
+    """One HierTrain iteration.  Returns (updated params, mean loss).
 
-    p_o = params
-    p_s = [params[:m] for m in m_s]
-    p_l = params[:m_l]
-
-    def iteration_loss(p_o: Params, p_s: List[Params], p_l: Params
-                       ) -> jax.Array:
-        # --- forward: every front-end up to its own cut ---
-        h, h_l = _fronts(stack, codec, p_s, s_streams, m_s, b_s, p_l, x_l,
-                         m_l, b_l)
-        # worker_o walks its segment list, merging arrivals at their cuts.
-        cur = x_o
-        prev = 0
-        for i in join_order:
-            if m_s[i] != prev:
-                with obs.scope("hier.cloud"):
-                    cur = stack.apply_segment(p_o, cur, prev, m_s[i])
-                prev = m_s[i]
-            with obs.scope("hier.merge"):
-                cur = jnp.concatenate([cur, h[i]], axis=0)
-        with obs.scope("hier.cloud"):
-            cur = stack.apply_segment(p_o, cur, prev, m_l)
-        with obs.scope("hier.merge"):
-            if h_l is not None:
-                cur = jnp.concatenate([cur, h_l], axis=0)
-            labels = jnp.concatenate(
-                [y_o] + [s_streams[i][1] for i in join_order] + [y_l],
-                axis=0)
-        with obs.scope("hier.cloud"):
-            logits = stack.apply_segment(p_o, cur, m_l, N)
-        return stack.sum_loss(logits, labels)
-
-    total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
-        iteration_loss, argnums=(0, 1, 2))(p_o, p_s, p_l)
-
-    # --- weight-update phase: layer-wise gradient exchange ---------------
-    return _exchange_update(params, g_o, g_s, g_l, m_s, b_s, m_l, b_l, lr,
-                            B), total_loss / B
-
-
-def _fronts(stack, codec, p_s: List[Params], s_streams, m_s, b_s,
-            p_l: Params, x_l: jax.Array, m_l: int, b_l: int):
-    """Every TASK-S stream's front segment up to its own cut, and TASK
-    L's up to ``m_l``, each through the wire codec where it crosses a
-    cut above 0.  Returns (per-stream activations, TASK L's)."""
-    h = []
-    for i in range(len(m_s)):
-        with obs.scope(f"hier.stream{i}"):
-            a = stack.apply_segment(p_s[i], s_streams[i][0], 0, m_s[i]) \
-                if b_s[i] else None
-            if codec is not None and a is not None and m_s[i] > 0:
-                a = codec(a)
-        h.append(a)
-    with obs.scope("hier.stream_l"):
-        h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
-        if codec is not None and h_l is not None and m_l > 0:
-            h_l = codec(h_l)
-    return h, h_l
-
-
-def _exchange_update(params: Params, g_o, g_s, g_l, m_s, b_s, m_l: int,
-                     b_l: int, lr: float, B: int) -> Params:
-    """Sum each front layer's per-sample-sum gradients over its copies,
-    then one SGD update scaled by ``1/B`` (exact batch-``B`` SGD)."""
-    new_params: Params = []
-    for i in range(len(params)):
-        g = g_o[i]
-        with obs.scope("hier.exchange"):
-            for d in range(len(m_s)):
-                if i < m_s[d] and b_s[d]:
-                    g = jax.tree.map(jnp.add, g, g_s[d][i])
-            if i < m_l and b_l:
-                g = jax.tree.map(jnp.add, g, g_l[i])
-        with obs.scope("hier.update"):
-            new_params.append(jax.tree.map(
-                lambda p, gg: p - lr * (gg / B), params[i], g))
-    return new_params
-
-
-def multi_hybrid_step_from_schedule(model, params: Params,
-                                    x: jax.Array, y: jax.Array,
-                                    sched: MultiSchedule, lr: float,
-                                    wire: str = "none"
-                                    ) -> Tuple[Params, jax.Array]:
-    return multi_hybrid_sgd_step(model, params, multi_split_batch(x, y,
-                                                                  sched),
-                                 sched.m_s, sched.m_l, lr, wire=wire)
-
-
-# ---------------------------------------------------------------------------
-# Two-level tree generalization: streams live under E edge servers; each
-# edge pre-merges the activations of its resident same-cut streams before
-# the cloud-side walk, and the cloud tail (layers m_l..N) can optionally
-# run data-parallel under shard_map on a device mesh.  Activation
-# concatenation is arithmetic-free, so with every stream on one edge
-# (E = 1) the produced params and loss are bit-identical to
-# :func:`multi_hybrid_sgd_step` — the sample order, every matmul batch
-# and the loss-sum reduction order coincide.
-# ---------------------------------------------------------------------------
-
-
-def tree_hybrid_sgd_step(model, params: Params,
-                         batches: Dict[str, object],
-                         m_s: Sequence[int], m_l: int, lr: float,
-                         wire: str = "none",
-                         stream_edge: Sequence[int] | None = None,
-                         cloud_mesh=None) -> Tuple[Params, jax.Array]:
-    """One tree HierTrain iteration.  Returns (updated params, mean loss).
+    ``params`` plays the role of the consensus weights each worker starts
+    the iteration with (they are equal after every weight-update phase).
+    ``batches`` is :func:`split_batch`'s output: worker_o's samples, one
+    ``(x, y)`` per TASK-S stream, and TASK L's.
 
     ``stream_edge[i]`` names the edge hosting TASK-S stream ``i`` (device
     streams sit under their radio's edge; an edge's own stream under
-    itself).  Streams sharing ``(cut, edge)`` are concatenated *on the
-    edge* into one activation block before joining worker_o's
-    ascending-cut walk — E merge points feeding the cloud merge, exactly
-    the two-level aggregation the topology describes.  ``cloud_mesh``
-    (optional) runs the cloud-resident tail segment ``m_l..N``
-    data-parallel over the mesh's dp axes via ``shard_map`` (two-stage
-    VJP: the front is differentiated with ``jax.vjp``, the tail's
-    value-and-grad runs *inside* the mapped body with ``psum``-reduced
-    parameter grads and loss); the default ``None`` keeps the single
-    ``value_and_grad`` program whose results are bit-identical to the
-    star path at E=1.
+    itself); ``None`` puts every stream on edge 0, which is the star and,
+    at ``M = 1``, the paper's triple.  Streams sharing ``(cut, edge)`` are
+    concatenated *on the edge* into one activation block before joining
+    worker_o's ascending-cut walk.  Concatenation is arithmetic-free, so
+    the sample order, every matmul batch and the loss-sum reduction
+    order are the same whichever edges the streams sit under.
+
+    ``wire`` selects the cut-point transfer codec (``repro.core.wire``):
+    ``"int8"`` quantizes the shipped activations forward and — via the
+    codec's custom VJP — the returning activation-gradients backward;
+    ``"none"`` applies no codec.  A cut at 0 is a raw-input upload (the
+    ``sample_bytes`` channel), so the codec only touches crossings with
+    ``m > 0``.
+
+    ``cloud_mesh`` (optional) runs the cloud-resident tail segment
+    ``m_l..N`` data-parallel over the mesh's dp axes via ``shard_map``
+    (:func:`_sharded_tail_grads`); the default ``None`` keeps the single
+    ``value_and_grad`` program.
     """
     stack = as_layerstack(model)
     N = stack.num_layers
@@ -360,8 +145,7 @@ def tree_hybrid_sgd_step(model, params: Params,
     B = b_o + sum(b_s) + b_l
     # Ascending-cut order with the hosting edge (then stream index)
     # breaking ties; maximal runs of equal (cut, edge) are one edge-side
-    # merge each.  With every stream on edge 0 this is exactly the star
-    # join order.
+    # merge each.
     join_order = sorted((i for i in range(M) if b_s[i]),
                         key=lambda i: (m_s[i], eo[i], i))
     groups: List[Tuple[int, List[int]]] = []
@@ -372,6 +156,8 @@ def tree_hybrid_sgd_step(model, params: Params,
         else:
             groups.append((m_s[i], [i]))
 
+    # Worker-local copies: stream i holds layers 1..m_s[i], TASK L 1..m_l,
+    # worker_o all of them.
     p_o = params
     p_s = [params[:m] for m in m_s]
     p_l = params[:m_l]
@@ -379,8 +165,18 @@ def tree_hybrid_sgd_step(model, params: Params,
     def front(p_o: Params, p_s: List[Params], p_l: Params) -> jax.Array:
         """Everything up to the cloud boundary ``m_l``: per-stream
         frontends, per-edge merges, worker_o's walk, TASK L's arrival."""
-        h, h_l = _fronts(stack, codec, p_s, s_streams, m_s, b_s, p_l, x_l,
-                         m_l, b_l)
+        h = []
+        for i in range(M):
+            with obs.scope(f"hier.stream{i}"):
+                a = stack.apply_segment(p_s[i], s_streams[i][0], 0, m_s[i]) \
+                    if b_s[i] else None
+                if codec is not None and a is not None and m_s[i] > 0:
+                    a = codec(a)
+            h.append(a)
+        with obs.scope("hier.stream_l"):
+            h_l = stack.apply_segment(p_l, x_l, 0, m_l) if b_l else None
+            if codec is not None and h_l is not None and m_l > 0:
+                h_l = codec(h_l)
         cur = x_o
         prev = 0
         for cut, members in groups:
@@ -407,9 +203,9 @@ def tree_hybrid_sgd_step(model, params: Params,
     if cloud_mesh is None:
         def iteration_loss(p_o: Params, p_s: List[Params], p_l: Params
                            ) -> jax.Array:
+            cur = front(p_o, p_s, p_l)
             with obs.scope("hier.cloud"):
-                logits = stack.apply_segment(p_o, front(p_o, p_s, p_l), m_l,
-                                             N)
+                logits = stack.apply_segment(p_o, cur, m_l, N)
             return stack.sum_loss(logits, labels)
 
         total_loss, (g_o, g_s, g_l) = jax.value_and_grad(
@@ -418,8 +214,38 @@ def tree_hybrid_sgd_step(model, params: Params,
         total_loss, g_o, g_s, g_l = _sharded_tail_grads(
             stack, front, labels, p_o, p_s, p_l, m_l, N, B, cloud_mesh)
 
+    # --- weight-update phase: layer-wise gradient exchange ---------------
     return _exchange_update(params, g_o, g_s, g_l, m_s, b_s, m_l, b_l, lr,
                             B), total_loss / B
+
+
+def hybrid_step_from_schedule(model, params: Params, x: jax.Array,
+                              y: jax.Array, sched: MultiSchedule, lr: float,
+                              wire: str = "none",
+                              stream_edge: Sequence[int] | None = None,
+                              cloud_mesh=None) -> Tuple[Params, jax.Array]:
+    return hybrid_sgd_step(model, params, split_batch(x, y, sched),
+                           sched.m_s, sched.m_l, lr, wire=wire,
+                           stream_edge=stream_edge, cloud_mesh=cloud_mesh)
+
+
+def _exchange_update(params: Params, g_o, g_s, g_l, m_s, b_s, m_l: int,
+                     b_l: int, lr: float, B: int) -> Params:
+    """Sum each front layer's per-sample-sum gradients over its copies,
+    then one SGD update scaled by ``1/B`` (exact batch-``B`` SGD)."""
+    new_params: Params = []
+    for i in range(len(params)):
+        g = g_o[i]
+        with obs.scope("hier.exchange"):
+            for d in range(len(m_s)):
+                if i < m_s[d] and b_s[d]:
+                    g = jax.tree.map(jnp.add, g, g_s[d][i])
+            if i < m_l and b_l:
+                g = jax.tree.map(jnp.add, g, g_l[i])
+        with obs.scope("hier.update"):
+            new_params.append(jax.tree.map(
+                lambda p, gg: p - lr * (gg / B), params[i], g))
+    return new_params
 
 
 def _sharded_tail_grads(stack, front, labels, p_o: Params,
@@ -502,20 +328,6 @@ def tree_stream_edges(profile, net, sched: MultiSchedule) -> Tuple[int, ...]:
     return tuple(out)
 
 
-def tree_hybrid_step_from_schedule(model, params: Params,
-                                   x: jax.Array, y: jax.Array,
-                                   sched: MultiSchedule, lr: float,
-                                   wire: str = "none",
-                                   stream_edge: Sequence[int] | None = None,
-                                   cloud_mesh=None
-                                   ) -> Tuple[Params, jax.Array]:
-    return tree_hybrid_sgd_step(model, params, multi_split_batch(x, y,
-                                                                 sched),
-                                sched.m_s, sched.m_l, lr, wire=wire,
-                                stream_edge=stream_edge,
-                                cloud_mesh=cloud_mesh)
-
-
 # ---------------------------------------------------------------------------
 # Compiled fast path.  The cuts and learning rate are static (they select
 # the program structure), the params are donated (the step consumes the old
@@ -595,60 +407,28 @@ def _named(fn: Callable, name: str) -> Callable:
     return fn
 
 
-def jitted_hybrid_step(model, m_s: int, m_l: int, lr: float,
-                       wire: str = "none") -> Callable:
-    """A compiled ``(params, batches) -> (new_params, loss)`` hybrid step
-    with static ``(m_s, m_l, lr, wire)`` and donated ``params``.  jax.jit
-    still specializes on the batch-split shapes at first call, so one
-    compiled step serves every iteration with the same schedule."""
-    key = ("hybrid", id(model), int(m_s), int(m_l), float(lr), str(wire))
-
-    def make():
-        def step(params: Params, batches):
-            return hybrid_sgd_step(model, params, batches, m_s, m_l, lr,
-                                   wire=wire)
-        return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
-    return _cached_step(key, model, make)
-
-
-def jitted_multi_hybrid_step(model, m_s: Sequence[int],
-                             m_l: int, lr: float,
-                             wire: str = "none") -> Callable:
-    """Compiled ``(params, batches) -> (new_params, loss)`` M-stream hybrid
-    step; the cut tuple ``(m_s, m_l)``, ``lr`` and ``wire`` are static,
-    ``params`` is donated, and executables are cached per cut tuple like
-    :func:`jitted_hybrid_step`."""
-    cuts = tuple(int(m) for m in m_s)
-    key = ("multi", id(model), cuts, int(m_l), float(lr), str(wire))
-
-    def make():
-        def step(params: Params, batches):
-            return multi_hybrid_sgd_step(model, params, batches, cuts,
-                                         m_l, lr, wire=wire)
-        return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
-    return _cached_step(key, model, make)
-
-
-def jitted_tree_hybrid_step(model, m_s: Sequence[int], m_l: int, lr: float,
-                            wire: str = "none",
-                            stream_edge: Sequence[int] | None = None,
-                            cloud_mesh=None) -> Callable:
-    """Compiled tree-step variant of :func:`jitted_multi_hybrid_step`;
-    the stream→edge map and the (optional) cloud mesh join the static
-    cache key — a mesh swap recompiles rather than reusing a program
-    lowered for the old device set."""
+def jitted_hybrid_step(model, m_s: Sequence[int], m_l: int, lr: float,
+                       wire: str = "none",
+                       stream_edge: Sequence[int] | None = None,
+                       cloud_mesh=None) -> Callable:
+    """A compiled ``(params, batches) -> (new_params, loss)`` hybrid step.
+    The cut tuple ``(m_s, m_l)``, ``lr``, ``wire``, the stream→edge map
+    and the (optional) cloud mesh are static and key the cache — a mesh
+    swap recompiles rather than reusing a program lowered for the old
+    device set; ``params`` is donated.  jax.jit still specializes on the
+    batch-split shapes at first call, so one compiled step serves every
+    iteration with the same schedule."""
     cuts = tuple(int(m) for m in m_s)
     edges = tuple(int(e) for e in stream_edge) if stream_edge is not None \
         else (0,) * len(cuts)
-    key = ("tree", id(model), cuts, int(m_l), float(lr), str(wire), edges,
+    key = ("step", id(model), cuts, int(m_l), float(lr), str(wire), edges,
            None if cloud_mesh is None else id(cloud_mesh))
 
     def make():
         def step(params: Params, batches):
-            return tree_hybrid_sgd_step(model, params, batches, cuts,
-                                        m_l, lr, wire=wire,
-                                        stream_edge=edges,
-                                        cloud_mesh=cloud_mesh)
+            return hybrid_sgd_step(model, params, batches, cuts, m_l, lr,
+                                   wire=wire, stream_edge=edges,
+                                   cloud_mesh=cloud_mesh)
         return jax.jit(_named(step, obs.STEP_PROGRAM), donate_argnums=0)
     return _cached_step(key, model, make)
 

@@ -188,19 +188,23 @@ def _ema_profile_update(prof, baseline, slow: Dict[str, float],
 def _loop_ops(topology: str, model, profile, net, cfg: "HierLoopConfig"):
     """Topology-native function bundle for :func:`_run_loop`.
 
-    The triple and star loops were line-for-line duplicates differing
-    only in which half of the forked surface they called; this bundle is
-    the collapse point (DESIGN.md §9).  History formats are preserved
-    per topology: the triple records scalar ``m_s`` and a 3-tuple ``b``,
-    the star records the ``m_s`` tuple and an (M+2)-tuple ``b``.
+    The planner side (solver, fill/period cost model, worker names) is
+    topology-native; the step is not: every topology runs the one hybrid
+    step (DESIGN.md §3), the triple as its M=1 star.  History formats are
+    preserved per topology: the triple records scalar ``m_s`` and a
+    3-tuple ``b``, the star and tree record the ``m_s`` tuple and an
+    (M+2)-tuple ``b``.
     """
+    from repro.core import scheduler
+    from repro.core.cost_model import MultiSchedule
+    from repro.core.hybrid_step import (jitted_hybrid_step, split_batch,
+                                        tree_stream_edges)
+
     if topology == "triple":
-        from repro.core import scheduler
         from repro.core.cost_model import WORKERS, _t_total
-        from repro.core.hybrid_step import jitted_hybrid_step, split_batch
         from repro.core.pipeline import t_period
 
-        return dict(
+        ops = dict(
             names=WORKERS,
             widx={w: i for i, w in enumerate(WORKERS)},
             solve=lambda p, warm=None: scheduler._solve_3w(
@@ -208,49 +212,44 @@ def _loop_ops(topology: str, model, profile, net, cfg: "HierLoopConfig"):
                 warm_start=warm),
             fill=lambda p, s: _t_total(p, net, s).total,
             period=lambda p, s: t_period(p, net, s),
-            step_fn=lambda s: jitted_hybrid_step(model, s.m_s, s.m_l,
-                                                 cfg.lr, wire=cfg.wire),
-            split=split_batch,
             hist=lambda s: {"m_s": s.m_s, "m_l": s.m_l,
                             "b": (s.b_o, s.b_s, s.b_l)},
             tag="hier",
         )
-    assert topology in ("star", "tree"), topology
-    from repro.core import scheduler
-    from repro.core.cost_model import _t_total_multi
-    from repro.core.hybrid_step import (jitted_multi_hybrid_step,
-                                        jitted_tree_hybrid_step,
-                                        multi_split_batch,
-                                        tree_stream_edges)
-    from repro.core.pipeline import t_period_multi
-
-    if topology == "tree":
-        # The tree step pre-merges each edge's same-cut streams; the
-        # stream→edge map depends on the live schedule, so it is
-        # re-derived per solve.  Straggler EMAs are already per-edge:
-        # every edge server is its own row of ``worker_names``.
-        step_fn = lambda s: jitted_tree_hybrid_step(  # noqa: E731
-            model, s.m_s, s.m_l, cfg.lr, wire=cfg.wire,
-            stream_edge=tree_stream_edges(profile, net, s))
-        tag = "tree-hier"
     else:
-        step_fn = lambda s: jitted_multi_hybrid_step(  # noqa: E731
-            model, s.m_s, s.m_l, cfg.lr, wire=cfg.wire)
-        tag = "multi-hier"
+        assert topology in ("star", "tree"), topology
+        # Straggler EMAs are already per-edge on a tree: every edge
+        # server is its own row of ``worker_names``.
+        from repro.core.cost_model import _t_total_multi
+        from repro.core.pipeline import t_period_multi
 
-    return dict(
-        names=profile.worker_names,
-        widx=profile.widx,
-        solve=lambda p, warm=None: scheduler._solve_multi(
-            p, net, cfg.batch, objective=cfg.objective, warm_start=warm),
-        fill=lambda p, s: _t_total_multi(p, net, s).total,
-        period=lambda p, s: t_period_multi(p, net, s),
-        step_fn=step_fn,
-        split=multi_split_batch,
-        hist=lambda s: {"m_s": s.m_s, "m_l": s.m_l,
-                        "b": (s.b_o, *s.b_s, s.b_l)},
-        tag=tag,
-    )
+        ops = dict(
+            names=profile.worker_names,
+            widx=profile.widx,
+            solve=lambda p, warm=None: scheduler._solve_multi(
+                p, net, cfg.batch, objective=cfg.objective,
+                warm_start=warm),
+            fill=lambda p, s: _t_total_multi(p, net, s).total,
+            period=lambda p, s: t_period_multi(p, net, s),
+            hist=lambda s: {"m_s": s.m_s, "m_l": s.m_l,
+                            "b": (s.b_o, *s.b_s, s.b_l)},
+            tag="tree-hier" if topology == "tree" else "multi-hier",
+        )
+
+    def step(params, x, y, s):
+        """One hybrid step on schedule ``s``.  On a tree each edge
+        pre-merges its same-cut streams; the stream→edge map depends on
+        the live schedule, so it is re-derived per call."""
+        ms = s if isinstance(s, MultiSchedule) \
+            else MultiSchedule.from_schedule(s)
+        edges = tree_stream_edges(profile, net, ms) if topology == "tree" \
+            else None
+        fn = jitted_hybrid_step(model, ms.m_s, ms.m_l, cfg.lr,
+                                wire=cfg.wire, stream_edge=edges)
+        return fn(params, split_batch(x, y, ms))
+
+    ops["step"] = step
+    return ops
 
 
 def _run_loop(cfg: HierLoopConfig, model, profile, net, data,
@@ -449,10 +448,9 @@ def _run_loop(cfg: HierLoopConfig, model, profile, net, data,
         b = data.batch(step)
         # Cached compiled step: static (m_s, m_l, lr), donated params — a
         # reschedule that keeps the cuts reuses the same executable.
-        step_fn = ops["step_fn"](sched)
-        params, loss = step_fn(params, ops["split"](
-            jax.numpy.asarray(b["x"]), jax.numpy.asarray(b["labels"]),
-            sched))
+        params, loss = ops["step"](
+            params, jax.numpy.asarray(b["x"]),
+            jax.numpy.asarray(b["labels"]), sched)
         losses.append(float(loss))
         if log and (step + 1) % 10 == 0:
             log(f"{ops['tag']} step {step+1}: loss={losses[-1]:.4f} "

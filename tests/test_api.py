@@ -258,7 +258,7 @@ def test_step_fn_bit_identical_to_legacy_jitted_step():
     from repro.core.hybrid_step import jitted_hybrid_step, split_batch
     model = _tiny_mlp()
     p = plan(model, _cnn_fleet(model, topology="triple"), 16)
-    sched = p.schedule
+    sched = MultiSchedule.from_schedule(p.schedule)
     data = _img_data(model, 16)
     b = data.batch(0)
     x, y = jnp.asarray(b["x"]), jnp.asarray(b["labels"])
@@ -270,6 +270,32 @@ def test_step_fn_bit_identical_to_legacy_jitted_step():
     assert float(new_l) == float(old_l)
     for a, b2 in zip(jax.tree.leaves(new_p), jax.tree.leaves(old_p)):
         assert (np.asarray(a) == np.asarray(b2)).all()
+
+
+def test_step_fn_of_triple_star_and_tree_fleets_is_one_program(
+        monkeypatch):
+    """Fleets of one profile whose schedules are equal run one compiled
+    step: the triple, the M=1 star and the E=1 tree share a single
+    ``_JIT_CACHE`` entry (the step has no topology fork)."""
+    import dataclasses
+
+    from repro.core import hybrid_step as hs
+    from repro.core.layerstack import as_layerstack
+    monkeypatch.setattr(hs, "_JIT_CACHE", hs._JitStepCache())
+    stack = as_layerstack(_tiny_mlp())
+    sched = Schedule("cloud", "device", "edge", 2, 3, 6, 5, 5)
+    fns = []
+    for topology in ("triple", "star", "tree"):
+        p = plan(stack, Fleet.from_table2(topology=topology), 16)
+        assert p.fleet.topology == topology
+        s = sched if topology == "triple" else \
+            MultiSchedule.from_schedule(sched)
+        p = dataclasses.replace(
+            p, result=dataclasses.replace(p.result, schedule=s))
+        p.step_fn(lr=0.05)
+        fns.append(hs.jitted_hybrid_step(stack, (2,), 3, 0.05))
+    assert len(hs._JIT_CACHE) == 1
+    assert fns[0] is fns[1] is fns[2]
 
 
 def test_trained_params_bit_identical_triple_vs_star_m1():

@@ -220,7 +220,7 @@ def test_tree_sharded_cloud_tier_multidev():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.cost_model import MultiSchedule
-        from repro.core.hybrid_step import tree_hybrid_step_from_schedule
+        from repro.core.hybrid_step import hybrid_step_from_schedule
         from repro.models.cnn import DenseSpec, LayeredModel
 
         specs = tuple(DenseSpec(f"fc{i}", 16) for i in range(4)) + \\
@@ -238,9 +238,9 @@ def test_tree_sharded_cloud_tier_multidev():
         x = jax.random.normal(kx, (24, 8), jnp.float32)
         y = jax.random.randint(ky, (24,), 0, 5)
         params = model.init(jax.random.PRNGKey(1))
-        p_ref, l_ref = tree_hybrid_step_from_schedule(
+        p_ref, l_ref = hybrid_step_from_schedule(
             model, params, x, y, sched, lr=0.05, stream_edge=eo)
-        p_sh, l_sh = tree_hybrid_step_from_schedule(
+        p_sh, l_sh = hybrid_step_from_schedule(
             model, params, x, y, sched, lr=0.05, stream_edge=eo,
             cloud_mesh=mesh)
         np.testing.assert_allclose(float(l_ref), float(l_sh), rtol=1e-6)
@@ -254,7 +254,7 @@ def test_tree_sharded_cloud_tier_multidev():
             s_workers=sched.s_workers, m_s=sched.m_s, m_l=3,
             b_o=5, b_s=(4, 3, 3, 5, 3), b_l=0)
         try:
-            tree_hybrid_step_from_schedule(
+            hybrid_step_from_schedule(
                 model, params, x[:23], y[:23], bad, lr=0.05,
                 stream_edge=eo, cloud_mesh=mesh)
             raise SystemExit("divisibility guard did not fire")

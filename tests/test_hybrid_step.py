@@ -10,9 +10,9 @@ import numpy as np
 import pytest
 from tests._compat import given, settings, st
 
-from repro.core.cost_model import Schedule
+from repro.core.cost_model import MultiSchedule, Schedule
 from repro.core.hybrid_step import (hybrid_step_from_schedule,
-                                    reference_sgd_step, split_batch, traffic)
+                                    reference_sgd_step, traffic)
 from repro.models.cnn import LayeredModel, ConvSpec, DenseSpec, lenet5
 
 jax.config.update("jax_enable_x64", False)
@@ -52,7 +52,7 @@ def test_hybrid_equals_reference_sgd(seed):
     lr = 0.05
     ref_params, ref_loss = reference_sgd_step(model, params, x, y, lr)
     hyb_params, hyb_loss = hybrid_step_from_schedule(
-        model, params, x, y, sched, lr)
+        model, params, x, y, MultiSchedule.from_schedule(sched), lr)
 
     assert hyb_loss == pytest.approx(float(ref_loss), rel=1e-5)
     for pr, ph in zip(ref_params, hyb_params):
@@ -67,8 +67,8 @@ def test_hybrid_equals_reference_on_lenet():
     x, y = make_batch(key, model, 10)
     sched = Schedule("cloud", "device", "edge", 2, 3, 4, 3, 3)
     ref_params, _ = reference_sgd_step(model, params, x, y, 0.01)
-    hyb_params, _ = hybrid_step_from_schedule(model, params, x, y, sched,
-                                              0.01)
+    hyb_params, _ = hybrid_step_from_schedule(
+        model, params, x, y, MultiSchedule.from_schedule(sched), 0.01)
     for pr, ph in zip(ref_params, hyb_params):
         np.testing.assert_allclose(pr["w"], ph["w"], rtol=5e-5, atol=1e-6)
 
@@ -80,7 +80,8 @@ def test_multi_step_training_descends_and_matches():
     key = jax.random.PRNGKey(1)
     params_ref = model.init(key)
     params_hyb = [dict(p) for p in params_ref]
-    sched = Schedule("edge", "device", "cloud", 1, 2, 4, 4, 4)
+    sched = MultiSchedule.from_schedule(
+        Schedule("edge", "device", "cloud", 1, 2, 4, 4, 4))
     losses = []
     for step in range(12):
         x, y = make_batch(jax.random.PRNGKey(100 + step), model, 12)
@@ -103,7 +104,8 @@ def test_degenerate_schedules():
     N = model.num_layers
     for sched in (Schedule("cloud", "device", "edge", 0, 0, 9, 0, 0),
                   Schedule("cloud", "device", "edge", N, N, 3, 3, 3)):
-        hyb, _ = hybrid_step_from_schedule(model, params, x, y, sched, 0.1)
+        hyb, _ = hybrid_step_from_schedule(
+            model, params, x, y, MultiSchedule.from_schedule(sched), 0.1)
         for pr, ph in zip(ref, hyb):
             np.testing.assert_allclose(pr["w"], ph["w"], rtol=2e-5,
                                        atol=2e-6)

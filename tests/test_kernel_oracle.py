@@ -290,11 +290,11 @@ def test_hybrid_step_pallas_matches_ref(m_s, m_l):
     # N = embed + (mamba2, hybrid) x 2 + head = 6 cut-points
     params = st_ref.init(jax.random.PRNGKey(0))
     x, y = st_ref.dummy_batch(jax.random.PRNGKey(1), 9)
-    batches = {"o": (x[:3], y[:3]), "s": (x[3:6], y[3:6]),
+    batches = {"o": (x[:3], y[:3]), "s": ((x[3:6], y[3:6]),),
                "l": (x[6:], y[6:])}
-    p_ref, loss_ref = hybrid_sgd_step(st_ref, params, batches, m_s, m_l,
+    p_ref, loss_ref = hybrid_sgd_step(st_ref, params, batches, (m_s,), m_l,
                                       lr=0.05)
-    p_pal, loss_pal = hybrid_sgd_step(st_pal, params, batches, m_s, m_l,
+    p_pal, loss_pal = hybrid_sgd_step(st_pal, params, batches, (m_s,), m_l,
                                       lr=0.05)
     np.testing.assert_allclose(float(loss_pal), float(loss_ref),
                                rtol=E2E_LOSS_RTOL)

@@ -69,14 +69,22 @@ def test_cnn_adapter_schedule_and_t_total_bitwise(build, testbed, ec_mbps):
     assert r_legacy.t_period == r_adapter.t_period
 
 
-def test_cnn_adapter_trained_params_bitwise():
+@pytest.mark.parametrize("seed,sched", [
+    (0, MultiSchedule.from_schedule(
+        Schedule("cloud", "device", "edge", 2, 3, 5, 4, 3))),
+    (3, MultiSchedule(worker_o="edge", worker_l="cloud",
+                      s_workers=("device_0", "device_1"), m_s=(1, 2),
+                      m_l=3, b_o=3, b_s=(2, 3), b_l=2)),
+], ids=["triple", "two_streams"])
+def test_cnn_adapter_trained_params_bitwise(seed, sched):
     model = tiny_mlp()
-    stack = CnnLayerStack(model)
-    key = jax.random.PRNGKey(0)
+    stack = as_layerstack(model)
+    assert isinstance(stack, CnnLayerStack)
+    key = jax.random.PRNGKey(seed)
     params = model.init(key)
-    x = jax.random.normal(key, (12,) + model.input_shape, jnp.float32)
-    y = jax.random.randint(key, (12,), 0, model.num_classes)
-    sched = Schedule("cloud", "device", "edge", 2, 3, 5, 4, 3)
+    x = jax.random.normal(key, (sched.batch,) + model.input_shape,
+                          jnp.float32)
+    y = jax.random.randint(key, (sched.batch,), 0, model.num_classes)
     p1, l1 = hs.hybrid_step_from_schedule(model, params, x, y, sched, 0.05)
     p2, l2 = hs.hybrid_step_from_schedule(stack, params, x, y, sched, 0.05)
     assert float(l1) == float(l2)
@@ -86,25 +94,6 @@ def test_cnn_adapter_trained_params_bitwise():
     r1, _ = hs.reference_sgd_step(model, params, x, y, 0.05)
     r2, _ = hs.reference_sgd_step(stack, params, x, y, 0.05)
     for a, b in zip(jax.tree.leaves(r1), jax.tree.leaves(r2)):
-        assert (np.asarray(a) == np.asarray(b)).all()
-
-
-def test_cnn_adapter_multi_step_bitwise():
-    model = tiny_mlp()
-    stack = as_layerstack(model)
-    key = jax.random.PRNGKey(3)
-    params = model.init(key)
-    x = jax.random.normal(key, (10, 8), jnp.float32)
-    y = jax.random.randint(key, (10,), 0, 5)
-    sched = MultiSchedule(worker_o="edge", worker_l="cloud",
-                          s_workers=("device_0", "device_1"), m_s=(1, 2),
-                          m_l=3, b_o=3, b_s=(2, 3), b_l=2)
-    p1, l1 = hs.multi_hybrid_step_from_schedule(model, params, x, y, sched,
-                                                0.05)
-    p2, l2 = hs.multi_hybrid_step_from_schedule(stack, params, x, y, sched,
-                                                0.05)
-    assert float(l1) == float(l2)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         assert (np.asarray(a) == np.asarray(b)).all()
 
 
@@ -231,23 +220,25 @@ def _fresh_cache(maxsize):
 def test_jit_cache_is_bounded_and_evicts_lru(monkeypatch):
     monkeypatch.setattr(hs, "_JIT_CACHE", _fresh_cache(3))
     model = tiny_mlp()
-    fns = [hs.jitted_hybrid_step(model, m, m, 0.1) for m in range(3)]
+    fns = [hs.jitted_hybrid_step(model, (m,), m, 0.1) for m in range(3)]
     assert len(hs._JIT_CACHE) == 3
     # hit: same (model, cuts, lr) returns the cached callable
-    assert hs.jitted_hybrid_step(model, 0, 0, 0.1) is fns[0]
+    assert hs.jitted_hybrid_step(model, (0,), 0, 0.1) is fns[0]
     # inserting a 4th evicts the least-recently-used entry (m=1: the m=0
     # entry was just touched)
-    hs.jitted_hybrid_step(model, 3, 3, 0.1)
+    hs.jitted_hybrid_step(model, (3,), 3, 0.1)
     assert len(hs._JIT_CACHE) == 3
-    assert ("hybrid", id(model), 1, 1, 0.1, "none") not in hs._JIT_CACHE
-    assert ("hybrid", id(model), 0, 0, 0.1, "none") in hs._JIT_CACHE
+    assert ("step", id(model), (1,), 1, 0.1, "none", (0,), None) \
+        not in hs._JIT_CACHE
+    assert ("step", id(model), (0,), 0, 0.1, "none", (0,), None) \
+        in hs._JIT_CACHE
 
 
 def test_jit_cache_releases_model_on_eviction(monkeypatch):
     monkeypatch.setattr(hs, "_JIT_CACHE", _fresh_cache(2))
     model = tiny_mlp()
     ref = weakref.ref(model)
-    hs.jitted_hybrid_step(model, 1, 1, 0.1)
+    hs.jitted_hybrid_step(model, (1,), 1, 0.1)
     del model
     gc.collect()
     # pinned while cached: the id-keyed handle stays valid
@@ -255,7 +246,7 @@ def test_jit_cache_releases_model_on_eviction(monkeypatch):
     # filling the cache with other models evicts the entry -> collectable
     keep = [tiny_mlp(width=8), tiny_mlp(width=12)]
     for m in keep:
-        hs.jitted_hybrid_step(m, 1, 1, 0.1)
+        hs.jitted_hybrid_step(m, (1,), 1, 0.1)
     gc.collect()
     assert ref() is None, "evicted model must be garbage-collectable"
 
@@ -263,12 +254,15 @@ def test_jit_cache_releases_model_on_eviction(monkeypatch):
 def test_jit_cache_still_caches_across_reschedules(monkeypatch):
     monkeypatch.setattr(hs, "_JIT_CACHE", _fresh_cache(8))
     model = tiny_mlp()
-    f1 = hs.jitted_hybrid_step(model, 1, 2, 0.1)
-    f2 = hs.jitted_hybrid_step(model, 2, 3, 0.1)
+    f1 = hs.jitted_hybrid_step(model, (1,), 2, 0.1)
+    f2 = hs.jitted_hybrid_step(model, (2,), 3, 0.1)
     assert f1 is not f2
-    assert hs.jitted_hybrid_step(model, 1, 2, 0.1) is f1
-    g1 = hs.jitted_multi_hybrid_step(model, (1,), 2, 0.1)
-    assert hs.jitted_multi_hybrid_step(model, (1,), 2, 0.1) is g1
+    assert hs.jitted_hybrid_step(model, (1,), 2, 0.1) is f1
+    # the explicit all-zero stream→edge map is the default's entry
+    assert hs.jitted_hybrid_step(model, (1,), 2, 0.1,
+                                 stream_edge=(0,)) is f1
+    g1 = hs.jitted_hybrid_step(model, (1, 1), 2, 0.1)
+    assert hs.jitted_hybrid_step(model, (1, 1), 2, 0.1) is g1
     r1 = hs.jitted_reference_step(model, 0.1)
     assert hs.jitted_reference_step(model, 0.1) is r1
     assert len(hs._JIT_CACHE) == 4

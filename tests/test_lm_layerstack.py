@@ -8,9 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.cost_model import Network, Schedule, StarNetwork
+from repro.core.cost_model import (MultiSchedule, Network, Schedule,
+                                   StarNetwork)
 from repro.core.hybrid_step import (hybrid_step_from_schedule,
-                                    multi_hybrid_step_from_schedule,
                                     reference_sgd_step)
 from repro.core.profiler import LM_TESTBED, analytic_profile, \
     multi_analytic_profile
@@ -91,7 +91,7 @@ def test_hybrid_exact_vs_reference_at_several_cuts(family):
         sched = Schedule("cloud", "device", "edge", m_s, m_l,
                          B - b_s - b_l, b_s, b_l)
         hyb_params, hyb_loss = hybrid_step_from_schedule(
-            stack, params, x, y, sched, lr)
+            stack, params, x, y, MultiSchedule.from_schedule(sched), lr)
         assert float(hyb_loss) == pytest.approx(float(ref_loss), rel=1e-5)
         for a, b in zip(jax.tree.leaves(ref_params),
                         jax.tree.leaves(hyb_params)):
@@ -99,9 +99,8 @@ def test_hybrid_exact_vs_reference_at_several_cuts(family):
                                        rtol=2e-4, atol=2e-5)
 
 
-def test_multi_hybrid_exact_two_streams():
+def test_hybrid_step_exact_two_streams():
     stack = _stack("attention")
-    from repro.core.cost_model import MultiSchedule
     key = jax.random.PRNGKey(2)
     params = stack.init(key)
     x, y = stack.dummy_batch(key, 9)
@@ -109,7 +108,7 @@ def test_multi_hybrid_exact_two_streams():
                           s_workers=("device_0", "device_1"), m_s=(1, 2),
                           m_l=4, b_o=3, b_s=(2, 2), b_l=2)
     ref_params, ref_loss = reference_sgd_step(stack, params, x, y, 0.05)
-    hyb_params, hyb_loss = multi_hybrid_step_from_schedule(
+    hyb_params, hyb_loss = hybrid_step_from_schedule(
         stack, params, x, y, sched, 0.05)
     assert float(hyb_loss) == pytest.approx(float(ref_loss), rel=1e-5)
     for a, b in zip(jax.tree.leaves(ref_params),
@@ -134,7 +133,7 @@ def test_solve_step_simulate_end_to_end(family):
     params = stack.init(key)
     x, y = stack.dummy_batch(key, B)
     new_params, loss = hybrid_step_from_schedule(
-        stack, params, x, y, res.schedule, 0.05)
+        stack, params, x, y, MultiSchedule.from_schedule(res.schedule), 0.05)
     assert np.isfinite(float(loss))
     assert len(new_params) == stack.num_layers
 

@@ -269,9 +269,9 @@ def _batch(model, B, seed=0):
 
 @settings(max_examples=15, deadline=None)
 @given(st.integers(0, 10_000))
-def test_multi_hybrid_equals_reference_sgd(seed):
+def test_multi_stream_step_equals_reference_sgd(seed):
     import jax
-    from repro.core.hybrid_step import (multi_hybrid_step_from_schedule,
+    from repro.core.hybrid_step import (hybrid_step_from_schedule,
                                         reference_sgd_step)
     rng = np.random.default_rng(seed)
     model = _tiny_mlp()
@@ -292,29 +292,35 @@ def test_multi_hybrid_equals_reference_sgd(seed):
         m_s=m_s, m_l=m_l, b_o=b_o, b_s=tuple(b_s), b_l=b_l)
     x, y = _batch(model, B, seed)
     params = model.init(jax.random.PRNGKey(seed))
-    hyb, _ = multi_hybrid_step_from_schedule(model, params, x, y, sched,
-                                             lr=0.05)
+    hyb, _ = hybrid_step_from_schedule(model, params, x, y, sched,
+                                       lr=0.05)
     ref, _ = reference_sgd_step(model, params, x, y, 0.05)
     for pr, ph in zip(ref, hyb):
         np.testing.assert_allclose(pr["w"], ph["w"], rtol=2e-5, atol=2e-6)
         np.testing.assert_allclose(pr["b"], ph["b"], rtol=2e-5, atol=2e-6)
 
 
-def test_multi_hybrid_m1_bit_identical_to_three_worker_step():
+def test_star_m1_step_bit_identical_to_triple_step():
+    """The triple fleet and the M=1 star fleet over the same profile,
+    pinned to one split schedule, give bit-identical params and loss
+    through ``Plan.step_fn``."""
+    import dataclasses
+
     import jax
-    from repro.core.hybrid_step import (hybrid_sgd_step,
-                                        multi_hybrid_sgd_step,
-                                        multi_split_batch, split_batch)
+    from repro.api import Fleet, plan
     model = _tiny_mlp()
     sched = Schedule("device", "edge", "cloud", 2, 4, 6, 5, 5)
     x, y = _batch(model, 16, seed=3)
-    params = model.init(jax.random.PRNGKey(3))
-    p3, l3 = hybrid_sgd_step(model, params, split_batch(x, y, sched),
-                             sched.m_s, sched.m_l, 0.05)
-    msched = MultiSchedule.from_schedule(sched)
-    pm, lm = multi_hybrid_sgd_step(
-        model, params, multi_split_batch(x, y, msched), msched.m_s,
-        msched.m_l, 0.05)
+    outs = []
+    for topology, s in (("triple", sched),
+                        ("star", MultiSchedule.from_schedule(sched))):
+        p = plan(model, Fleet.from_table2(topology=topology), 16)
+        assert p.fleet.topology == topology
+        p = dataclasses.replace(
+            p, result=dataclasses.replace(p.result, schedule=s))
+        outs.append(p.step_fn(lr=0.05)(model.init(jax.random.PRNGKey(3)),
+                                       x, y))
+    (p3, l3), (pm, lm) = outs
     assert float(l3) == float(lm)
     for a, b in zip(jax.tree.leaves(p3), jax.tree.leaves(pm)):
         assert (np.asarray(a) == np.asarray(b)).all()

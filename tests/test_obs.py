@@ -104,8 +104,8 @@ def test_last_step_is_the_program_that_ran_and_is_held_weakly():
     step = _split_plan(stack).step_fn(lr=2e-2)
     params = stack.init(jax.random.PRNGKey(0))
     x, y = _batch(stack)
-    fn = hs.jitted_multi_hybrid_step(stack, SPLIT.m_s, SPLIT.m_l, 2e-2)
-    direct = fn.lower(params, hs.multi_split_batch(x, y, SPLIT)).compile(
+    fn = hs.jitted_hybrid_step(stack, SPLIT.m_s, SPLIT.m_l, 2e-2)
+    direct = fn.lower(params, hs.split_batch(x, y, SPLIT)).compile(
     ).as_text()
     params, _ = step(params, x, y)
     got, args, tokens = obs.last_step()
@@ -183,8 +183,8 @@ def test_two_compiles_of_one_step_give_identical_instruction_names():
     for _ in range(2):
         hs.clear_jit_cache()
         jax.clear_caches()
-        fn = hs.jitted_multi_hybrid_step(stack, SPLIT.m_s, SPLIT.m_l, 1e-2)
-        texts.append(fn.lower(params, hs.multi_split_batch(x, y, SPLIT))
+        fn = hs.jitted_hybrid_step(stack, SPLIT.m_s, SPLIT.m_l, 1e-2)
+        texts.append(fn.lower(params, hs.split_batch(x, y, SPLIT))
                      .compile().as_text())
     names = [[m.group(1) for m in map(INSTR.match, t.splitlines()) if m]
              for t in texts]

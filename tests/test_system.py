@@ -50,6 +50,7 @@ def test_microbatched_step_matches_plain():
 
 def test_hier_cnn_end_to_end():
     from repro.core.cost_model import Network
+    from repro.core.cost_model import MultiSchedule
     from repro.core.hybrid_step import hybrid_step_from_schedule
     from repro.core.profiler import analytic_profile
     from repro.core.scheduler import solve
@@ -58,7 +59,7 @@ def test_hier_cnn_end_to_end():
     model = lenet5()
     profile = analytic_profile(model)
     net = Network(bw_de=5e6 / 8, bw_ec=2e6 / 8)
-    sched = solve(profile, net, 32).schedule
+    sched = MultiSchedule.from_schedule(solve(profile, net, 32).schedule)
     data = SyntheticImages(model.input_shape, model.num_classes, 32,
                            seed=0)
     params = model.init(jax.random.PRNGKey(0))

@@ -156,20 +156,27 @@ def _batch(model, B, seed=0):
 
 
 def test_tree_step_e1_bit_identical_to_star_step():
-    from repro.core.hybrid_step import (multi_hybrid_step_from_schedule,
-                                        tree_hybrid_step_from_schedule)
+    """An E=1 tree fleet and its star, pinned to one split schedule
+    (two same-cut streams), give bit-identical params and loss through
+    ``Plan.step_fn``."""
+    import dataclasses
+
+    from repro import api
     model = _tiny_mlp()
     sched = MultiSchedule(worker_o="cloud", worker_l="edge",
                           s_workers=("device_0", "device_1", "device_2"),
                           m_s=(2, 2, 1), m_l=4, b_o=6, b_s=(4, 3, 3),
                           b_l=8)
     x, y = _batch(model, 24, seed=1)
-    params = model.init(jax.random.PRNGKey(1))
-    ps, ls = multi_hybrid_step_from_schedule(model, params, x, y, sched,
-                                             lr=0.05)
-    pt, lt = tree_hybrid_step_from_schedule(model, params, x, y, sched,
-                                            lr=0.05,
-                                            stream_edge=(0, 0, 0))
+    outs = []
+    for fleet in _api_fleets():
+        p = api.plan(model, fleet, 24)
+        p = dataclasses.replace(
+            p, result=dataclasses.replace(p.result, schedule=sched))
+        assert p.stream_edges() == (0, 0, 0)
+        outs.append(p.step_fn(lr=0.05)(model.init(jax.random.PRNGKey(1)),
+                                       x, y))
+    (ps, ls), (pt, lt) = outs
     assert float(ls) == float(lt)
     for a, b in zip(jax.tree.leaves(ps), jax.tree.leaves(pt)):
         assert (np.asarray(a) == np.asarray(b)).all()
@@ -181,8 +188,8 @@ def test_tree_step_equals_reference_sgd(seed):
     """Random E=2 tree schedules (including same-cut streams split
     across different edges — distinct merge groups) are exact batch-B
     SGD."""
-    from repro.core.hybrid_step import (reference_sgd_step,
-                                        tree_hybrid_step_from_schedule,
+    from repro.core.hybrid_step import (hybrid_step_from_schedule,
+                                        reference_sgd_step,
                                         tree_stream_edges)
     rng = np.random.default_rng(seed)
     model = _tiny_mlp()
@@ -206,7 +213,7 @@ def test_tree_step_equals_reference_sgd(seed):
         m_s=m_s, m_l=m_l, b_o=b_o, b_s=tuple(b_s), b_l=b_l)
     x, y = _batch(model, B, seed)
     params = model.init(jax.random.PRNGKey(seed))
-    hyb, _ = tree_hybrid_step_from_schedule(
+    hyb, _ = hybrid_step_from_schedule(
         model, params, x, y, sched, lr=0.05,
         stream_edge=tree_stream_edges(tprof, tnet, sched))
     ref, _ = reference_sgd_step(model, params, x, y, 0.05)

@@ -12,8 +12,7 @@ import pytest
 from repro.core.cost_model import MultiSchedule, Schedule
 from repro.core.hybrid_step import (hybrid_sgd_step,
                                     hybrid_step_from_schedule,
-                                    jitted_hybrid_step,
-                                    multi_hybrid_sgd_step, traffic)
+                                    jitted_hybrid_step, traffic)
 from repro.core.wire import (SCALE_BYTES, apply_wire, int8_wire_bytes,
                              validate_wire, wire_act_bytes, wire_codec,
                              wire_grad_bytes)
@@ -286,8 +285,9 @@ def test_traffic_asymmetric_uncompressed_accounting():
 
 def _cnn_fixture():
     model = _tiny_mlp()
-    sched = Schedule(worker_o="edge", worker_s="device", worker_l="cloud",
-                     m_s=2, m_l=3, b_o=8, b_s=8, b_l=8)
+    sched = MultiSchedule.from_schedule(Schedule(
+        worker_o="edge", worker_s="device", worker_l="cloud",
+        m_s=2, m_l=3, b_o=8, b_s=8, b_l=8))
     key = jax.random.PRNGKey(0)
     params = model.init(key)
     x = jax.random.normal(jax.random.fold_in(key, 1), (24, 8), jnp.float32)
@@ -328,15 +328,24 @@ def test_wire_int8_changes_results_within_budget():
 
 
 def test_multi_matches_triple_at_m1_with_wire():
-    """The M=1 trace-identity invariant survives the codec."""
-    model, sched, params, x, y = _cnn_fixture()
-    batches = {"o": (x[:8], y[:8]), "s": (x[8:16], y[8:16]),
-               "l": (x[16:], y[16:])}
-    p3, l3 = hybrid_sgd_step(model, params, batches, sched.m_s, sched.m_l,
-                             0.05, wire="int8")
-    mbatches = {"o": batches["o"], "s": (batches["s"],), "l": batches["l"]}
-    pm, lm = multi_hybrid_sgd_step(model, params, mbatches, (sched.m_s,),
-                                   sched.m_l, 0.05, wire="int8")
+    """The M=1 bit-identity of the triple and star fleets survives the
+    codec: both fleets, int8 on the wire and pinned to one split
+    schedule, give bit-identical params and loss through
+    ``Plan.step_fn``."""
+    import dataclasses
+
+    from repro.api import Fleet, plan
+    model, msched, params, x, y = _cnn_fixture()
+    outs = []
+    for topology, s in (("triple", msched.to_schedule()), ("star", msched)):
+        p = plan(model, Fleet.from_table2(topology=topology, wire="int8"),
+                 24)
+        assert p.fleet.topology == topology and p.wire == "int8"
+        p = dataclasses.replace(
+            p, result=dataclasses.replace(p.result, schedule=s))
+        outs.append(p.step_fn(lr=0.05)(jax.tree.map(jnp.array, params),
+                                       x, y))
+    (p3, l3), (pm, lm) = outs
     assert float(l3) == float(lm)
     for a, b in zip(jax.tree.leaves(p3), jax.tree.leaves(pm)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -344,9 +353,9 @@ def test_multi_matches_triple_at_m1_with_wire():
 
 def test_jit_cache_keys_include_wire():
     model = _tiny_mlp()
-    f_none = jitted_hybrid_step(model, 2, 3, 0.05)
-    f_none2 = jitted_hybrid_step(model, 2, 3, 0.05, wire="none")
-    f_int8 = jitted_hybrid_step(model, 2, 3, 0.05, wire="int8")
+    f_none = jitted_hybrid_step(model, (2,), 3, 0.05)
+    f_none2 = jitted_hybrid_step(model, (2,), 3, 0.05, wire="none")
+    f_int8 = jitted_hybrid_step(model, (2,), 3, 0.05, wire="int8")
     assert f_none is f_none2
     assert f_int8 is not f_none
 
@@ -357,10 +366,10 @@ def test_codec_skips_input_uploads():
     stack = _lm_stack(seq_len=16)
     params = stack.init(jax.random.PRNGKey(0))
     x, y = stack.dummy_batch(jax.random.PRNGKey(1), 6)
-    batches = {"o": (x[:2], y[:2]), "s": (x[2:4], y[2:4]),
+    batches = {"o": (x[:2], y[:2]), "s": ((x[2:4], y[2:4]),),
                "l": (x[4:], y[4:])}
     # m_s = 0: worker_s's samples are raw-input uploads
-    p, loss = hybrid_sgd_step(stack, params, batches, 0, 2, 0.05,
+    p, loss = hybrid_sgd_step(stack, params, batches, (0,), 2, 0.05,
                               wire="int8")
     assert np.isfinite(float(loss))
 
